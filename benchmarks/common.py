@@ -1,7 +1,6 @@
 """Shared helpers for the benchmark suite."""
 from __future__ import annotations
 
-import os
 import sys
 import time
 
@@ -26,13 +25,6 @@ def npe_for(scenario_name: str) -> int:
     return 4096 if scenario_name.startswith("dc") else 256
 
 
-def bench_processes() -> int:
-    """Benchmarks run the portfolio inline unless SCAR_PORTFOLIO_PROCS is
-    set: per-call wall times stay comparable across runs, and the in-process
-    CostDB cache is shared across the configs of one scenario."""
-    return int(os.environ.get("SCAR_PORTFOLIO_PROCS", "1"))
-
-
 def sweep(scenario_name: str, metric: str = "edp", configs=None,
           rows: int = 3, cols: int = 3, **cfg_kw) -> dict:
     """Run every MCM config on a scenario; returns {name: outcome}."""
@@ -41,7 +33,7 @@ def sweep(scenario_name: str, metric: str = "edp", configs=None,
                      standalone=standalone,
                      cfg=SearchConfig(metric=metric, **cfg_kw), label=name)
             for name, pattern, standalone in (configs or CONFIG_SET)]
-    results = run_portfolio(jobs, processes=bench_processes())
+    results = run_portfolio(jobs)
     return {r.job.name: r.outcome for r in results}
 
 

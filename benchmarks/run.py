@@ -40,7 +40,10 @@ def main() -> None:
                     help="write per-bench BENCH_<name>.json files here")
     args = ap.parse_args()
 
+    from repro.launch.platform import use_compile_cache
+
     from . import online_benches, paper_benches, system_benches
+    use_compile_cache()
     benches = paper_benches.ALL + system_benches.ALL + online_benches.ALL
     print("name,us_per_call,derived")
     t0 = time.time()

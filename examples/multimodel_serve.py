@@ -17,7 +17,7 @@ sys.path.insert(0, "src")
 
 import jax
 
-from repro.launch.mesh import auto_axis_types, mesh_context
+from jax.sharding import AxisType
 import jax.numpy as jnp
 import numpy as np
 
@@ -27,9 +27,11 @@ from repro.models import ModelDims, get_arch, init_params
 from repro.models.steps import make_prefill_step
 from repro.models.testing import reduced, synth_batch
 from repro.multimodel import ServeRequest, plan
+from repro.launch.platform import use_compile_cache
 
 
 def main() -> None:
+    use_compile_cache()
     rows, cols = 4, 2
     reqs = [ServeRequest("minitron-8b", batch=4, seq=64),
             ServeRequest("qwen2-moe-a2.7b", batch=4, seq=64),
@@ -49,11 +51,12 @@ def main() -> None:
         coords = [divmod(c, cols) for c in pl_.chips]
         devs = np.array([devices[r, c] for r, c in coords])
         mesh = jax.sharding.Mesh(devs.reshape(len(devs), 1),
-                                 ("data", "model"), **auto_axis_types(2))
+                                 ("data", "model"),
+                                 axis_types=(AxisType.Auto,) * 2)
         dims = ModelDims.create(cfg, tp=1)
         batch = max(req.batch, len(devs))
         specs = shd.make_specs(cfg, mesh, batch)
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             params = init_params(cfg, jax.random.PRNGKey(0), dims)
             b = synth_batch(cfg, batch=batch, seq=req.seq)
             b.pop("labels", None)

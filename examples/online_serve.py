@@ -27,9 +27,11 @@ sys.path.insert(0, "src")
 from repro import obs
 from repro.core import TRACE_PRESETS, SearchConfig, get_trace
 from repro.online import OnlinePolicy, qos_report, simulate, slo_report
+from repro.launch.platform import use_compile_cache
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--trace", default="dc_churn_smoke",
                     choices=sorted(TRACE_PRESETS))

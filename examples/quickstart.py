@@ -11,9 +11,11 @@ sys.path.insert(0, "src")
 
 from repro.core import SearchConfig, get_scenario
 from repro.core.portfolio import SweepJob, run_portfolio
+from repro.launch.platform import use_compile_cache
 
 
 def main() -> None:
+    use_compile_cache()
     sc = get_scenario("xr10_vr_gaming")  # EyeCod + HandSP (Table II #10)
     print(f"scenario: {sc.name}  models: "
           f"{[(m.name, len(m)) for m in sc.models]}\n")

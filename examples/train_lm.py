@@ -15,9 +15,11 @@ import tempfile
 sys.path.insert(0, "src")
 
 from repro.launch.train import main as train_main
+from repro.launch.platform import use_compile_cache
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--size", choices=["tiny", "100m"], default="tiny")

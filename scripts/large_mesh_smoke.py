@@ -23,12 +23,14 @@ import numpy as np
 from repro.core.paths import path_cache_info
 from repro.core.portfolio import run_portfolio, sweep_grid
 from repro.core.scenarios import LARGE_MESHES
+from repro.launch.platform import use_compile_cache
 
 SCENARIOS = ["dc4_lms_seg_image", "xr7_ar_gaming"]
 PATTERNS = ["het_cb", "het_sides"]
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument(
         "--processes",

@@ -7,7 +7,7 @@ that dominates large-mesh schedule construction.  This module compiles the
 whole window search into ONE jitted program per (mesh, window-shape) bucket:
 
 * ``protocol_program`` — beam *combination* only, over host-scored float64
-  candidate tables.  Run under scoped ``jax.experimental.enable_x64`` the
+  candidate tables.  Run under scoped x64 (``launch.platform.enable_x64``) the
   per-stage ops are the reference's exact IEEE operations (one ``max``, one
   ``add``, one ``multiply``) and ``lax.top_k``'s lowest-flat-index tie rule
   reproduces the reference's stable row-major acceptance order, so plans,

@@ -447,7 +447,9 @@ class DeviceBeamEngine:
       reference's stable row-major acceptance order, so plans, metrics and
       the explored cloud are bit-identical to ``reference_combine`` /
       ``BeamEngine`` (asserted on all ten paper scenarios in
-      ``tests/test_device_search.py``).
+      ``tests/test_device_search.py``).  A CPU parity form: it raises on a
+      TPU, whose XLA emulates float64 without IEEE rounding (on a v5e the
+      explored cloud came back a few ulps off the reference).
     * ``combine_window`` — the fused throughput form ``scheduler.schedule``
       routes ``algo="beam_jax"`` through.  The host only *constructs*
       candidates (PROV + SEG + tensor assembly); scoring
@@ -471,7 +473,8 @@ class DeviceBeamEngine:
     interpret: bool = False
     comm_model: str = "analytic"
 
-    def _kernels(self) -> bool:
+    def uses_kernels(self) -> bool:
+        """Whether this engine runs the Pallas kernels (True on a TPU)."""
         if self.use_kernel is not None:
             return self.use_kernel
         from .evaluator import _jax_platform
@@ -480,12 +483,17 @@ class DeviceBeamEngine:
     def combine(self, db: CostDB, mcm: MCM, sets: list[ModelCandidateSet],
                 prev_end: dict[int, int],
                 metric: str = "edp") -> WindowSearchResult:
-        from jax.experimental import enable_x64
-
         from repro.launch import platform as launch_platform
 
         from . import device_search as ds
+        from .evaluator import _jax_platform
 
+        if _jax_platform() == "tpu":
+            raise RuntimeError(
+                "DeviceBeamEngine.combine is a CPU parity form: its float64 "
+                "ops are not IEEE-exact on a TPU, so it cannot match the "
+                "reference there; schedule() routes algo='beam_jax' through "
+                "combine_window")
         sets = sorted(sets, key=lambda s: -float(np.min(s.lat)))
         n_words = max(1, (mcm.n_chiplets + 63) // 64)
         m_models = len(sets)
@@ -506,13 +514,14 @@ class DeviceBeamEngine:
         t0 = ds.probe_width(n_pad, int(keeps.max()))
         ds.note_program("protocol", (m_models, n_pad, n_words, self.beam,
                                      metric, self.max_expansions, t0,
-                                     self._kernels(), self.interpret))
+                                     self.uses_kernels(), self.interpret))
         with obs.span("device_combine", cat="engine", engine="beam_jax",
-                      models=m_models, n_pad=n_pad), enable_x64():
+                      models=m_models, n_pad=n_pad), \
+                launch_platform.enable_x64():
             out = ds.protocol_program(
                 masks, lat, energy, sizes, keeps, beam=self.beam,
                 metric=metric, max_exp=self.max_expansions, t0=t0,
-                use_kernel=self._kernels(), interpret=self.interpret)
+                use_kernel=self.uses_kernels(), interpret=self.interpret)
             # the single host transfer of the whole combination
             parents, cands, tlats, tes, counts, fails = \
                 launch_platform.device_fetch(out)
@@ -559,7 +568,7 @@ class DeviceBeamEngine:
             segs = top_k_segmentations(db, mcm, s, e, alloc[mi],
                                        k=cfg.seg_top_k, cap=cfg.seg_cap,
                                        metric=cfg.metric)
-            use_kernel = self._kernels()
+            use_kernel = self.uses_kernels()
             cand, tiers, (words, chips, seg_arr) = assemble_candidates(
                 mcm, mi, (s, e), segs, prev_end.get(mi),
                 path_cap=cfg.path_cap, frontier_cap=cfg.frontier_cap,
@@ -589,14 +598,14 @@ class DeviceBeamEngine:
             "fused",
             (tuple(tuple(a.shape for a in i[0]) + (i[1].shape,) for i in
                    inputs), tuple(modes), n_active, n_pad, self.beam, keep,
-             metric, self.max_expansions, t0, t1, self._kernels(),
+             metric, self.max_expansions, t0, t1, self.uses_kernels(),
              self.interpret, congestion))
         out = ds.fused_program(
             tuple(inputs), modes=tuple(modes), pkg=mcm.pkg,
             mcm_cols=mcm.cols, n_active=n_active, n_pad=n_pad,
             beam=self.beam, keep=keep, metric=metric,
             max_exp=self.max_expansions, t0=t0, t1=t1,
-            use_kernel=self._kernels(), interpret=self.interpret,
+            use_kernel=self.uses_kernels(), interpret=self.interpret,
             mcm_rows=mcm.rows, congestion=congestion,
             noc=mcm.noc if congestion else None)
         # the single counted host transfer of the whole window search

@@ -69,18 +69,15 @@ _JAX_PLATFORM: Optional[str] = None
 
 
 def _jax_platform() -> str:
-    """Resolve ``jax.default_backend()``, or "unavailable" without jax.
+    """``jax.default_backend()``, resolved once per process.
 
-    The "auto" backend then stays on numpy instead of failing at
-    dispatch time.
+    A JAX that cannot start raises here: the device path is never swapped
+    for numpy behind the caller's back.
     """
     global _JAX_PLATFORM
     if _JAX_PLATFORM is None:
-        try:
-            import jax
-            _JAX_PLATFORM = jax.default_backend()
-        except Exception:  # jax unavailable/misconfigured
-            _JAX_PLATFORM = "unavailable"
+        import jax
+        _JAX_PLATFORM = jax.default_backend()
     return _JAX_PLATFORM
 
 
@@ -100,10 +97,7 @@ def resolve_backend(backend: Optional[str] = None,
         return b
     if work is not None and work < _auto_threshold():
         return "numpy"
-    platform = _jax_platform()
-    if platform == "unavailable":
-        return "numpy"
-    return "pallas" if platform == "tpu" else "jax_ref"
+    return "pallas" if _jax_platform() == "tpu" else "jax_ref"
 
 
 def eval_candidates(db: CostDB, mcm: MCM, cand: BatchedModelCandidates,

@@ -13,9 +13,11 @@ every caller:
   along in ``SearchConfig.eval_backend`` (``repro.core.evaluator``), so
   large-mesh sweeps score candidates on the jax path inside each worker
   while small meshes stay on numpy — no per-worker wiring needed.
-* ``run_portfolio`` executes a job list inline (``processes<=1``) or on a
-  spawn-based process pool; jobs are dispatched grouped by CostDB affinity
-  so identical (scenario/trace, MCM) points share one worker's warm caches.
+* ``run_portfolio`` executes a job list inline (the default) or, for jobs
+  that stay on the host, on a spawn-based process pool; jobs are
+  dispatched grouped by CostDB affinity so identical (scenario/trace, MCM)
+  points share one worker's warm caches.  A chip belongs to one process, so
+  jobs that may dispatch to the JAX device never go to workers.
 * ``sweep_grid`` / ``trace_sweep_grid`` build the full cross products.
 
 Results come back as ``SweepResult`` records carrying the full
@@ -33,6 +35,7 @@ from typing import Optional
 
 from repro import obs
 
+from .engine import DeviceBeamEngine, get_engine
 from .scenarios import get_scenario, mesh_shape
 from .scheduler import ScheduleOutcome, SearchConfig, run_config
 
@@ -164,11 +167,21 @@ def _init_worker(path: list[str]) -> None:
 
 
 def default_processes() -> int:
-    """Worker count: $SCAR_PORTFOLIO_PROCS, else min(n_cpu, 8)."""
-    env = os.environ.get("SCAR_PORTFOLIO_PROCS")
-    if env is not None:
-        return max(1, int(env))
-    return max(1, min(os.cpu_count() or 1, 8))
+    """Worker count: $SCAR_PORTFOLIO_PROCS, else 1 (inline)."""
+    return max(1, int(os.environ.get("SCAR_PORTFOLIO_PROCS", "1")))
+
+
+def touches_device(job) -> bool:
+    """Whether ``job`` may dispatch work to JAX's default device.
+
+    Only a host search engine scoring candidates on numpy stays off it:
+    ``eval_backend="auto"`` sends large batches to the jax path, and
+    ``algo="beam_jax"`` (or its ``SCAR_SEARCH_BACKEND`` reroute) runs the
+    whole window search there.
+    """
+    cfg = job.cfg or SearchConfig()
+    return (cfg.eval_backend != "numpy"
+            or isinstance(get_engine(cfg), DeviceBeamEngine))
 
 
 def run_portfolio(jobs: list,
@@ -179,7 +192,9 @@ def run_portfolio(jobs: list,
     ``processes``: None -> ``default_processes()``; <=1 -> inline in this
     process (no pool, easiest to debug); otherwise a spawn-based pool, which
     sidesteps fork-safety issues with an already-initialised JAX runtime in
-    the parent.
+    the parent.  A pool takes host-only jobs (``touches_device`` false):
+    asking for one over jobs that may use the device raises ``ValueError``,
+    because every worker would try to take the same chip.
 
     Jobs are submitted grouped by ``_db_affinity`` in contiguous chunks, so
     jobs sharing a (scenario/trace, MCM) land on the same worker and hit its
@@ -192,6 +207,12 @@ def run_portfolio(jobs: list,
     processes = min(processes, len(jobs)) if jobs else 1
     if processes <= 1:
         return [_run_job(j) for j in jobs]
+    on_device = [j.name for j in jobs if touches_device(j)]
+    if on_device:
+        raise ValueError(
+            f"{len(on_device)} job(s) may use the JAX device (first: "
+            f"{on_device[0]!r}); run them inline (processes=1), or give "
+            f"them SearchConfig(eval_backend='numpy') with a host engine")
     import math
     import multiprocessing as mp
     groups: dict[tuple, list[int]] = {}

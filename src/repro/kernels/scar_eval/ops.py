@@ -15,10 +15,9 @@ Two device forms share those terms:
 * ``use_kernel=False`` (jax_ref): within a segment the chiplet class is
   constant, so segment compute sums are differences of the per-class
   prefix-summed cost tables gathered at segment boundaries — O(B*S) work,
-  no ``[B, Lw]`` tensor is ever materialised.  The fast form on non-MXU
-  backends.
-* ``use_kernel=True`` (Pallas): the dense one-hot form, where the segment
-  reduction is an MXU matvec over VMEM-resident candidate blocks.
+  no ``[B, Lw]`` tensor is ever materialised.  The fast form off the TPU.
+* ``use_kernel=True`` (Pallas): the dense per-layer form, where the kernel
+  reduces every layer of VMEM-resident, candidate-major blocks.
 
 Shape bucketing keeps the jit cache small across a search run: the segment
 axis ``S`` is shrunk to the per-batch max segment count (padded segments
@@ -104,16 +103,13 @@ def evaluate_traceable(lat_tab, e_tab, w_bytes, out_bytes, class_map, chips,
     valid = exists.astype(jnp.float32)
 
     if use_kernel:
-        # dense one-hot form: the Pallas kernel turns the segment reduction
-        # into MXU matvecs over VMEM-resident candidate blocks
+        # dense per-layer form: the Pallas kernel reduces every layer of
+        # VMEM-resident, candidate-major blocks
         layer_cls = jnp.take_along_axis(seg_cls, seg_id, axis=1)  # [B, Lw]
-        cls_oh = (layer_cls[..., None] == jnp.arange(C, dtype=jnp.int32)
-                  ).astype(jnp.float32)
-        seg_oh = (seg_id[..., None] == jnp.arange(S, dtype=jnp.int32)
-                  ).astype(jnp.float32)
-        pipe = jnp.full((B, 1), 1.0 if pipelined else 0.0, jnp.float32)
-        return scar_eval(lat_tab, e_tab, cls_oh, seg_oh, comm_lat, comm_e,
-                         valid, pipe, block_b=block_b, interpret=interpret)
+        pipe = jnp.full((1, B), 1.0 if pipelined else 0.0, jnp.float32)
+        return scar_eval(lat_tab, e_tab, layer_cls.T, seg_id.T, comm_lat.T,
+                         comm_e.T, valid.T, pipe, block_b=block_b,
+                         interpret=interpret).T
 
     # jax_ref: the class is constant within a segment, so the segment
     # compute sum is a difference of the prefix-summed per-class table at

@@ -10,9 +10,16 @@ into VMEM-resident blocks and emits the popcount of each intersection
 
 Inputs:
   beam_words  [Bm, W]  uint32  packed beam occupancy (W = 2 * ceil(C / 64))
-  cand_words  [N, W]   uint32  packed candidate occupancy
+  cand_t      [W, N]   uint32  packed candidate occupancy, word-major
 Output:
   conflicts   [Bm, N]  int32   popcount of the word-wise AND
+
+Candidates arrive word-major so the candidate axis fills the vector lanes:
+the kernel accumulates one ``[Bm, bn]`` popcount plane per word (W is 2 at
+6x6 and 8 at 16x16).  The row-major ``[Bm, bn, W]`` intersection would put
+W on the lanes, pad it to 128 in VMEM, and unroll into code that took the
+TPU compiler ~50 s for one 16x16 block.  Popcounts are summed as int32: the
+TPU compiler has no reduction over unsigned integers.
 
 ``ops.conflict_counts`` is the jitted wrapper (jax_ref twin:
 ``ops.conflict_counts_traceable``); ``ref.conflict_counts_ref`` the scalar
@@ -23,21 +30,27 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+
+# Block index constant of the index maps.  A bare ``0`` becomes int64 when
+# the caller traces under x64 (``engine.DeviceBeamEngine.combine``), and
+# the TPU compiler refuses int64 block indices.
+_ZERO = np.int32(0)
 
 
 def _search_kernel(beam_ref, cand_ref, out_ref):
-    beam = beam_ref[...]                                  # [Bm, W]
-    cand = cand_ref[...]                                  # [bn, W]
-    inter = beam[:, None, :] & cand[None, :, :]           # [Bm, bn, W]
-    counts = jnp.sum(jax.lax.population_count(inter), axis=-1)
-    out_ref[...] = counts.astype(jnp.int32)
+    acc = jnp.zeros(out_ref.shape, jnp.int32)             # [Bm, bn]
+    for w in range(cand_ref.shape[0]):
+        inter = beam_ref[:, w:w + 1] & cand_ref[w:w + 1, :]
+        acc += jax.lax.population_count(inter).astype(jnp.int32)
+    out_ref[...] = acc
 
 
-def scar_search(beam_words, cand_words, *, block_n: int = 2048,
+def scar_search(beam_words, cand_t, *, block_n: int = 2048,
                 interpret: bool = False):
     bm, w = beam_words.shape
-    n = cand_words.shape[0]
+    n = cand_t.shape[1]
     block_n = min(block_n, n)
     assert n % block_n == 0
     grid = (n // block_n,)
@@ -45,10 +58,10 @@ def scar_search(beam_words, cand_words, *, block_n: int = 2048,
         _search_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bm, w), lambda b: (0, 0)),
-            pl.BlockSpec((block_n, w), lambda b: (b, 0)),
+            pl.BlockSpec((bm, w), lambda b: (_ZERO, _ZERO)),
+            pl.BlockSpec((w, block_n), lambda b: (_ZERO, b)),
         ],
-        out_specs=pl.BlockSpec((bm, block_n), lambda b: (0, b)),
+        out_specs=pl.BlockSpec((bm, block_n), lambda b: (_ZERO, b)),
         out_shape=jax.ShapeDtypeStruct((bm, n), jnp.int32),
         interpret=interpret,
-    )(beam_words, cand_words)
+    )(beam_words, cand_t)

@@ -39,7 +39,7 @@ def conflict_counts_traceable(beam_words, cand_words, *,
             cand_words = jnp.concatenate(
                 [cand_words,
                  jnp.zeros((pad,) + cand_words.shape[1:], cand_words.dtype)])
-        out = scar_search(beam_words, cand_words, block_n=block_n,
+        out = scar_search(beam_words, cand_words.T, block_n=block_n,
                           interpret=interpret)
         return out[:, :n]
     inter = beam_words[:, None, :] & cand_words[None, :, :]

@@ -16,10 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed TPUCompilerParams -> CompilerParams across jax releases
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 
 def _ssd_kernel(q_ref, k_ref, v_ref, a_ref, o_ref, state_ref, *,
                 chunk: int):
@@ -81,7 +77,7 @@ def ssd_scan(q: jax.Array, k: jax.Array, v: jax.Array, a: jax.Array, *,
         out_specs=pl.BlockSpec((1, chunk, P), lambda b, c: (b, c, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, L, P), v.dtype),
         scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, a)

@@ -25,7 +25,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.distributed import sharding as shd
 from repro.launch import cells as cellmod
-from repro.launch.mesh import mesh_context, make_production_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.models import ModelDims, get_arch, make_train_step
 from repro.models.steps import make_decode_step, make_prefill_step
 from repro.optim import AdamWConfig, adamw
@@ -319,7 +319,7 @@ def run_cell(cell: cellmod.Cell, mesh, mesh_name: str,
     fn, args, in_sh, out_sh, donate = build_cell(cell, mesh, overrides)
     jitted = jax.jit(fn, in_shardings=in_sh, out_shardings=out_sh,
                      donate_argnums=donate)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         lowered = jitted.lower(*args)
         rec["lower_s"] = round(time.time() - t0, 1)
         t1 = time.time()

@@ -8,35 +8,20 @@ pure data-parallel / gradient-reduction axis (DCN-friendly traffic only).
 from __future__ import annotations
 
 import jax
-
-
-def mesh_context(mesh: jax.sharding.Mesh):
-    """``jax.set_mesh(mesh)`` where available; on older jax the ``Mesh``
-    resource-env context manager is the equivalent ambient-mesh scope."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
-
-
-def auto_axis_types(n: int) -> dict:
-    """``axis_types`` kwarg for mesh constructors, or {} on jax versions
-    that predate ``jax.sharding.AxisType`` (explicit-sharding rollout)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **auto_axis_types(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_mesh(shape: tuple[int, ...],
               axes: tuple[str, ...]) -> jax.sharding.Mesh:
-    return jax.make_mesh(shape, axes, **auto_axis_types(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_test_mesh(n_devices: int | None = None) -> jax.sharding.Mesh:
@@ -48,4 +33,4 @@ def make_test_mesh(n_devices: int | None = None) -> jax.sharding.Mesh:
             model = m
             break
     return jax.make_mesh((n // model, model), ("data", "model"),
-                         **auto_axis_types(2))
+                         axis_types=(AxisType.Auto,) * 2)

@@ -8,11 +8,14 @@ the bayespec config idiom:
 * ``set_platform("cpu"|"gpu"|"tpu")`` — pin the jax platform (call before
   any array op; jax latches the backend on first use);
 * ``jax_enable_x64(True)`` — process-global float64 (the device search path
-  does NOT need this: it scopes x64 per-program via
-  ``jax.experimental.enable_x64``);
+  does NOT need this: it scopes x64 per-program via ``enable_x64()``, the
+  one scoped-x64 helper, built on ``jax.enable_x64``);
 * ``set_host_device_count(n)`` — split the host CPU into ``n`` XLA devices
   (``--xla_force_host_platform_device_count``) for multi-device tests.
-  Must run before jax initialises its backends.
+  Must run before jax initialises its backends;
+* ``use_compile_cache()`` — place JAX's persistent compilation cache.  Entry
+  points (scripts, examples, the benchmark harness) call it; library code
+  and tests never do.
 
 It is also the *accounting point* for host-device synchronisation:
 ``device_fetch`` is the sanctioned way to materialise device values on the
@@ -34,12 +37,17 @@ import re
 
 from repro.obs import registry as _obs_registry
 
-__all__ = ["set_platform", "jax_enable_x64", "set_host_device_count",
-           "device_fetch", "sync_count", "reset_sync_count"]
+__all__ = ["set_platform", "jax_enable_x64", "enable_x64",
+           "set_host_device_count", "use_compile_cache", "device_fetch",
+           "sync_count", "reset_sync_count"]
 
 # The one sync counter; module-level handle so device_fetch pays a single
 # attribute increment per call.
 _SYNC = _obs_registry.counter("launch.platform.sync_count")
+
+# The repository root, the directory above src/repro/launch/.
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
 
 
 def set_platform(platform: str = "cpu") -> None:
@@ -56,14 +64,43 @@ def set_platform(platform: str = "cpu") -> None:
 def jax_enable_x64(use_x64: bool = True) -> None:
     """Process-global 64-bit mode (``jax.config jax_enable_x64``).
 
-    Prefer the scoped ``jax.experimental.enable_x64`` context manager where
-    possible — the device search engine uses the scoped form so the float32
-    evaluator paths are unaffected; this global switch exists for scripts
-    that want x64 everywhere (bayespec idiom).
+    Prefer the scoped ``enable_x64()`` context manager where possible — the
+    device search engine uses the scoped form so the float32 evaluator
+    paths are unaffected; this global switch exists for scripts that want
+    x64 everywhere (bayespec idiom).
     """
     import jax
 
     jax.config.update("jax_enable_x64", use_x64)
+
+
+def enable_x64():
+    """Scoped 64-bit mode: ``with enable_x64(): ...``.
+
+    Arrays created and programs traced inside the block default to 64-bit
+    types; the previous setting returns on exit.
+    """
+    import jax
+
+    return jax.enable_x64(True)
+
+
+def use_compile_cache() -> str:
+    """Place JAX's persistent compilation cache; returns its directory.
+
+    When ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and this
+    sets nothing.  Otherwise the cache goes to ``<checkout>/.jax_cache``: a
+    fixed path, because the path is part of what a later process must find
+    again.  Call it from entry points before the first compile.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR", "").strip()
+    if env:
+        return env
+    import jax
+
+    path = os.path.join(_CHECKOUT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def set_host_device_count(n: int) -> None:

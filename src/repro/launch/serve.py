@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.distributed import sharding as shd
-from repro.launch.mesh import mesh_context, make_production_mesh, make_test_mesh
+from repro.launch.mesh import make_production_mesh, make_test_mesh
 from repro.models import ModelDims, get_arch, init_params
 from repro.models.steps import make_decode_step, make_prefill_step
 from repro.models.testing import reduced, synth_batch
@@ -46,7 +46,7 @@ def main(argv=None) -> dict:
     max_len = args.prompt_len + args.gen
     specs = shd.make_specs(cfg, mesh, args.batch)
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         params = init_params(cfg, jax.random.PRNGKey(args.seed), dims)
         batch = synth_batch(cfg, batch=args.batch, seq=args.prompt_len,
                             seed=args.seed)

@@ -19,7 +19,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.data.pipeline import StepWatchdog, SyntheticLM
 from repro.distributed import checkpoint as ckpt
 from repro.distributed import sharding as shd
-from repro.launch.mesh import mesh_context, make_production_mesh, make_test_mesh
+from repro.launch.mesh import make_production_mesh, make_test_mesh
 from repro.models import ModelDims, get_arch, init_params, make_train_step
 from repro.models.testing import reduced
 from repro.optim import AdamWConfig, adamw
@@ -54,7 +54,7 @@ def main(argv=None) -> dict:
     specs = shd.make_specs(cfg, mesh, args.batch)
     opt = AdamWConfig(lr=args.lr, warmup_steps=20, total_steps=args.steps)
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         params = init_params(cfg, jax.random.PRNGKey(args.seed), dims)
         pspec = shd.param_specs(cfg, params)
         params = jax.tree.map(

@@ -153,16 +153,15 @@ def realize(plan_: PodPlan, requests: list[ServeRequest], devices=None,
         devs = np.array([devices[r, c] for r, c in coords])
         n = len(devs)
         tp = n if (cfg.n_heads % n == 0 and shd.style_for(cfg) == "tp") else 1
-        from repro.launch.mesh import mesh_context, auto_axis_types
         mesh = jax.sharding.Mesh(
             devs.reshape(n // tp if tp > 1 else n, tp if tp > 1 else 1),
             ("data", "model"),
-            **auto_axis_types(2))
+            axis_types=(jax.sharding.AxisType.Auto,) * 2)
         dims = ModelDims.create(cfg, tp=tp)
         batch = max(req.batch, n // tp) if tp == 1 else req.batch
         specs = shd.make_specs(cfg, mesh, batch)
         fn = make_prefill_step(cfg, dims, max_cache_len=req.seq, specs=specs)
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             b = synth_batch(cfg, batch=batch, seq=req.seq) \
                 if reduced_archs else None
             if b is not None:

@@ -95,6 +95,22 @@ def test_device_beam_interpret_kernel_parity():
     assert dev.explored == ref.explored
 
 
+def test_device_beam_combine_refuses_tpu(monkeypatch):
+    """The float64 protocol form is a CPU parity form: on a TPU, whose
+    float64 is not IEEE-exact, it raises instead of returning near-misses."""
+    from repro.core import evaluator
+
+    sc = get_scenario("xr7_ar_gaming")
+    mcm = make_mcm("het_sides", n_pe=256)
+    cfg = SearchConfig()
+    db, windows = _windows(sc, mcm, cfg)
+    sets, prev_end = windows[0]
+    monkeypatch.setattr(evaluator, "_jax_platform", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="CPU parity form"):
+        DeviceBeamEngine(beam=cfg.beam).combine(db, mcm, sets, prev_end,
+                                                metric=cfg.metric)
+
+
 # ------------------------ fused schedule contract ---------------------------
 
 def test_fused_schedule_matches_host_and_sync_contract(monkeypatch):
@@ -151,8 +167,6 @@ def test_quantize_scores_jax_matches_numpy():
     maps to one value stay collapsed on device too."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
-
     from repro.core.quantize import quantize_scores_jax
 
     rng = np.random.default_rng(0)
@@ -160,7 +174,7 @@ def test_quantize_scores_jax_matches_numpy():
         10.0 ** rng.uniform(-9, 9, 512),
         [0.0, np.inf, 1.0, 1.0 + 1e-12],
     ])
-    with enable_x64():
+    with lp.enable_x64():
         got = np.asarray(jax.jit(
             lambda x: quantize_scores_jax(x, sig=SCORE_SIG))(s))
     ref = quantize_scores(s, sig=SCORE_SIG)

@@ -5,7 +5,7 @@ import os
 
 import jax
 
-from repro.launch.mesh import auto_axis_types, mesh_context
+from jax.sharding import AxisType
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -58,7 +58,7 @@ def test_checkpoint_elastic_restore_new_sharding(tmp_path):
     t = {"w": jnp.arange(32, dtype=jnp.float32).reshape(8, 4)}
     ckpt.save(str(tmp_path), 1, t)
     n = len(jax.devices())
-    mesh = jax.make_mesh((n,), ("data",), **auto_axis_types(1))
+    mesh = jax.make_mesh((n,), ("data",), axis_types=(AxisType.Auto,))
     from jax.sharding import NamedSharding, PartitionSpec as P
     sh = {"w": NamedSharding(mesh, P("data" if 8 % n == 0 else None, None))}
     restored, _ = ckpt.restore(str(tmp_path), t, shardings=sh)
@@ -99,9 +99,9 @@ def test_watchdog_flags_stragglers():
 def test_compressed_psum_close_to_exact():
     from repro.distributed.compress import compressed_psum
     n = len(jax.devices())
-    mesh = jax.make_mesh((n,), ("pod",), **auto_axis_types(1))
+    mesh = jax.make_mesh((n,), ("pod",), axis_types=(AxisType.Auto,))
     x = jax.random.normal(jax.random.PRNGKey(0), (1024,))
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         out = compressed_psum(x, mesh, axis="pod")
     exact = x * n  # replicated input summed n times
     rel = float(jnp.max(jnp.abs(out - exact)) / jnp.max(jnp.abs(exact)))

@@ -110,7 +110,8 @@ def test_scar_eval_kernel_matches_core_evaluator_seeded(pipelined, prev_end):
 
 def test_scar_eval_dense_ref_matches_kernel():
     """``scar_eval_ref`` (the dense one-hot jnp oracle the Pallas kernel is
-    written against) still mirrors the kernel block-for-block."""
+    written against) still mirrors the kernel block-for-block, on
+    candidate-major inputs with a mix of pipelined and sequential rows."""
     from repro.kernels.scar_eval import scar_eval, scar_eval_ref
     rng = np.random.default_rng(0)
     B, L, C, S = 32, 12, 2, 4
@@ -122,15 +123,15 @@ def test_scar_eval_dense_ref_matches_kernel():
         _, inv = np.unique(seg[b], return_inverse=True)
         seg[b] = inv
     n_segs = seg.max(axis=1) + 1
-    cls_oh = jnp.asarray((cls[..., None] == np.arange(C)), jnp.float32)
-    seg_oh = jnp.asarray((seg[..., None] == np.arange(S)), jnp.float32)
-    valid = jnp.asarray(np.arange(S)[None] < n_segs[:, None], jnp.float32)
-    comm_lat = jnp.asarray(rng.uniform(0, 1e-4, (B, S)), jnp.float32) * valid
-    comm_e = jnp.asarray(rng.uniform(0, 1e-3, (B, S)), jnp.float32) * valid
-    pipe = jnp.asarray(rng.integers(0, 2, (B, 1)), jnp.float32)
-    out_k = scar_eval(lat_tab, e_tab, cls_oh, seg_oh, comm_lat, comm_e,
+    cls = jnp.asarray(cls.T, jnp.int32)                    # [L, B]
+    seg = jnp.asarray(seg.T, jnp.int32)
+    valid = jnp.asarray(np.arange(S)[:, None] < n_segs[None], jnp.float32)
+    comm_lat = jnp.asarray(rng.uniform(0, 1e-4, (S, B)), jnp.float32) * valid
+    comm_e = jnp.asarray(rng.uniform(0, 1e-3, (S, B)), jnp.float32) * valid
+    pipe = jnp.asarray(rng.integers(0, 2, (1, B)), jnp.float32)
+    out_k = scar_eval(lat_tab, e_tab, cls, seg, comm_lat, comm_e,
                       valid, pipe, block_b=16, interpret=True)
-    out_r = scar_eval_ref(lat_tab, e_tab, cls_oh, seg_oh, comm_lat, comm_e,
+    out_r = scar_eval_ref(lat_tab, e_tab, cls, seg, comm_lat, comm_e,
                           valid, pipe)
     np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r),
                                rtol=1e-6, atol=1e-12)

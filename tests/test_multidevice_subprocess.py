@@ -12,11 +12,11 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys; sys.path.insert(0, "src")
 import jax, jax.numpy as jnp
 from repro.distributed.compress import compressed_psum
-from repro.launch.mesh import auto_axis_types, mesh_context
+from jax.sharding import AxisType
 n = len(jax.devices()); assert n == 8, n
-mesh = jax.make_mesh((n,), ("pod",), **auto_axis_types(1))
+mesh = jax.make_mesh((n,), ("pod",), axis_types=(AxisType.Auto,))
 x = jax.random.normal(jax.random.PRNGKey(0), (4096,))
-with mesh_context(mesh):
+with jax.set_mesh(mesh):
     out = compressed_psum(x, mesh, axis="pod")
 exact = x * n
 rel = float(jnp.max(jnp.abs(out - exact)) / jnp.max(jnp.abs(exact)))
@@ -32,17 +32,17 @@ import jax, jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding
 from repro.distributed import sharding as shd
-from repro.launch.mesh import auto_axis_types, mesh_context
+from jax.sharding import AxisType
 from repro.models import ModelDims, get_arch, init_params, make_train_step
 from repro.models.testing import reduced, synth_batch
 from repro.optim import AdamWConfig, adamw
 
 cfg = reduced(get_arch("minitron-8b"))
-mesh = jax.make_mesh((4, 2), ("data", "model"), **auto_axis_types(2))
+mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 dims = ModelDims.create(cfg, tp=2)
 specs = shd.make_specs(cfg, mesh, 8)
 opt = AdamWConfig(lr=1e-2, warmup_steps=1, total_steps=50)
-with mesh_context(mesh):
+with jax.set_mesh(mesh):
     params = init_params(cfg, jax.random.PRNGKey(0), dims)
     pspec = shd.param_specs(cfg, params)
     params = jax.tree.map(

@@ -207,7 +207,8 @@ def test_online_simulation_emits_spans_and_report_gauges():
 def test_portfolio_merges_worker_spans_and_counters():
     obs.enable()
     obs.reset()
-    jobs = sweep_grid(["xr10_vr_gaming", "xr8_outdoors"], ["het_cb"])
+    jobs = sweep_grid(["xr10_vr_gaming", "xr8_outdoors"], ["het_cb"],
+                      eval_backend="numpy")
     run_portfolio(jobs, processes=2)
     tr = obs.tracer()
     job_evs = [e for e in tr.events if e["name"] == "job"]

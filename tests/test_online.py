@@ -229,10 +229,12 @@ def test_schedule_incremental_matches_schedule_with_anchors():
 def test_trace_portfolio_inline_and_parallel_parity():
     jobs = trace_sweep_grid(["dc_churn_smoke"], ["het_cross"],
                             rows=3, cols=3, n_pe=1024, modes=("warm",),
-                            path_cap=32, seg_cap=64, n_splits=2)
+                            path_cap=32, seg_cap=64, n_splits=2,
+                            eval_backend="numpy")
     jobs.append(TraceJob(trace="xr8_cadence", pattern="het_sides",
                          rows=3, cols=3, n_pe=256,
-                         cfg=SearchConfig(path_cap=32, seg_cap=64)))
+                         cfg=SearchConfig(path_cap=32, seg_cap=64,
+                                          eval_backend="numpy")))
     assert len({j.name for j in jobs}) == len(jobs)
     ser = run_portfolio(jobs, processes=1)
     par = run_portfolio(jobs, processes=2)
