@@ -43,7 +43,8 @@ def main() -> None:
     from repro.launch.platform import use_compile_cache
 
     from . import online_benches, paper_benches, system_benches
-    use_compile_cache()
+    use_compile_cache(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     benches = paper_benches.ALL + system_benches.ALL + online_benches.ALL
     print("name,us_per_call,derived")
     t0 = time.time()

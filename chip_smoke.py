@@ -181,7 +181,7 @@ def main() -> None:
     info = device_info(jax.devices())
     label = f"{info['kind']} x{info['count']}"
     print(f"device: platform={info['platform']} kind={info['kind']} "
-          f"count={info['count']}; compile cache {use_compile_cache()}")
+          f"count={info['count']}; compile cache {use_compile_cache(root)}")
 
     dc4 = get_scenario("dc4_lms_seg_image")
     schedule_phase("A", label, dc4,

@@ -31,7 +31,8 @@ from repro.launch.platform import use_compile_cache
 
 
 def main() -> None:
-    use_compile_cache()
+    use_compile_cache(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     rows, cols = 4, 2
     reqs = [ServeRequest("minitron-8b", batch=4, seq=64),
             ServeRequest("qwen2-moe-a2.7b", batch=4, seq=64),

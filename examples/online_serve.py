@@ -20,6 +20,7 @@ trace-driven MCM reconfiguration over the named candidate patterns.
 from __future__ import annotations
 
 import argparse
+import os
 import sys
 
 sys.path.insert(0, "src")
@@ -31,7 +32,8 @@ from repro.launch.platform import use_compile_cache
 
 
 def main() -> None:
-    use_compile_cache()
+    use_compile_cache(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     ap = argparse.ArgumentParser()
     ap.add_argument("--trace", default="dc_churn_smoke",
                     choices=sorted(TRACE_PRESETS))

@@ -5,6 +5,7 @@
 Reproduces the paper's core result on one scenario: the SCAR scheduler on a
 heterogeneous MCM vs the homogeneous Simba baselines.
 """
+import os
 import sys
 
 sys.path.insert(0, "src")
@@ -15,7 +16,8 @@ from repro.launch.platform import use_compile_cache
 
 
 def main() -> None:
-    use_compile_cache()
+    use_compile_cache(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     sc = get_scenario("xr10_vr_gaming")  # EyeCod + HandSP (Table II #10)
     print(f"scenario: {sc.name}  models: "
           f"{[(m.name, len(m)) for m in sc.models]}\n")

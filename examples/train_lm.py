@@ -9,6 +9,7 @@ The driver is `repro.launch.train` — the same code path the production
 launcher uses, including auto-resume from the newest valid checkpoint.
 """
 import argparse
+import os
 import sys
 import tempfile
 
@@ -19,7 +20,8 @@ from repro.launch.platform import use_compile_cache
 
 
 def main() -> None:
-    use_compile_cache()
+    use_compile_cache(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--size", choices=["tiny", "100m"], default="tiny")

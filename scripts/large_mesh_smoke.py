@@ -6,14 +6,17 @@ that candidate construction (``paths.frontier_paths``) and window
 combination (``engine.BeamEngine``) are both vectorized.  It runs a small
 scenario x pattern portfolio on every mesh in ``scenarios.LARGE_MESHES``,
 checks each outcome is finite and validated, and prints one CSV row per
-point plus the path-cache statistics.
+point plus the path-cache statistics.  The sweep runs inline: its jobs keep
+``eval_backend="auto"``, so large candidate batches score on the JAX path,
+and a chip belongs to one process.
 
-Usage: PYTHONPATH=src python scripts/large_mesh_smoke.py [--processes N]
+Usage: PYTHONPATH=src python scripts/large_mesh_smoke.py [--meshes 8x8 ...]
 """
 
 from __future__ import annotations
 
 import argparse
+import os
 import sys
 
 sys.path.insert(0, "src")
@@ -30,14 +33,9 @@ PATTERNS = ["het_cb", "het_sides"]
 
 
 def main() -> None:
-    use_compile_cache()
+    use_compile_cache(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     ap = argparse.ArgumentParser()
-    ap.add_argument(
-        "--processes",
-        type=int,
-        default=1,
-        help="portfolio worker processes (default: inline)",
-    )
     ap.add_argument(
         "--meshes",
         nargs="*",
@@ -53,7 +51,7 @@ def main() -> None:
         path_cap=512,
         seg_cap=128,
     )
-    results = run_portfolio(jobs, processes=args.processes)
+    results = run_portfolio(jobs, processes=1)
 
     print("name,edp,latency_s,energy_j,wall_s")
     failures = 0

@@ -10,9 +10,9 @@ every caller:
   overrides, never live objects, so jobs ship cheaply to workers);
   ``TraceJob`` is its online analogue (a ``scenarios.TRACE_PRESETS`` trace
   replayed through ``repro.online.simulate``).  The evaluator backend rides
-  along in ``SearchConfig.eval_backend`` (``repro.core.evaluator``), so
-  large-mesh sweeps score candidates on the jax path inside each worker
-  while small meshes stay on numpy — no per-worker wiring needed.
+  along in ``SearchConfig.eval_backend`` (``repro.core.evaluator``): under
+  ``"auto"`` large-mesh sweeps score candidates on the jax path, so they run
+  inline, while a sweep pinned to ``"numpy"`` on a host engine may be pooled.
 * ``run_portfolio`` executes a job list inline (the default) or, for jobs
   that stay on the host, on a spawn-based process pool; jobs are
   dispatched grouped by CostDB affinity so identical (scenario/trace, MCM)

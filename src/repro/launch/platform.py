@@ -13,7 +13,7 @@ the bayespec config idiom:
 * ``set_host_device_count(n)`` — split the host CPU into ``n`` XLA devices
   (``--xla_force_host_platform_device_count``) for multi-device tests.
   Must run before jax initialises its backends;
-* ``use_compile_cache()`` — place JAX's persistent compilation cache.  Entry
+* ``use_compile_cache(root)`` — place JAX's persistent compilation cache.  Entry
   points (scripts, examples, the benchmark harness) call it; library code
   and tests never do.
 
@@ -44,10 +44,6 @@ __all__ = ["set_platform", "jax_enable_x64", "enable_x64",
 # The one sync counter; module-level handle so device_fetch pays a single
 # attribute increment per call.
 _SYNC = _obs_registry.counter("launch.platform.sync_count")
-
-# The repository root, the directory above src/repro/launch/.
-_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.dirname(os.path.abspath(__file__)))))
 
 
 def set_platform(platform: str = "cpu") -> None:
@@ -85,20 +81,23 @@ def enable_x64():
     return jax.enable_x64(True)
 
 
-def use_compile_cache() -> str:
+def use_compile_cache(root: str) -> str:
     """Place JAX's persistent compilation cache; returns its directory.
 
     When ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and this
-    sets nothing.  Otherwise the cache goes to ``<checkout>/.jax_cache``: a
-    fixed path, because the path is part of what a later process must find
-    again.  Call it from entry points before the first compile.
+    sets nothing.  Otherwise the cache goes to ``<root>/.jax_cache``, where
+    ``root`` is the checkout the calling entry point runs from (each derives
+    it from its own ``__file__``, so an installed ``repro`` package never
+    decides the path): a fixed path, because the path is part of what a
+    later process must find again.  Call it from entry points before the
+    first compile.
     """
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR", "").strip()
     if env:
         return env
     import jax
 
-    path = os.path.join(_CHECKOUT, ".jax_cache")
+    path = os.path.join(os.path.abspath(root), ".jax_cache")
     jax.config.update("jax_compilation_cache_dir", path)
     return path
 
