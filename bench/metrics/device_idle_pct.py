@@ -1,0 +1,8 @@
+"""Share of the traced window in which no operation ran on the chip:
+1 - busy / window, from the profiler trace (``trace_reduce``)."""
+
+
+def read(run):
+    if run.trace is None or run.trace.devices == 0:
+        return None
+    return 100.0 * (1.0 - run.trace.busy_s / run.trace.window_s)
