@@ -194,10 +194,11 @@ def beam_scan(pool, full, *, beam: int, metric: str, max_exp: int,
             new_e = b_e[:, None] + ce_s[None, :]
             sc = jnp.where(flat.reshape(beam, n_s),
                            metric_score(new_lat, new_e, metric), jnp.inf)
-            # scarlint: ignore[SL004] -- beam-stage ordering deliberately
-            # mirrors BeamEngine.combine's unquantised f64 argsort bit-for-
-            # bit; only the per-model pool ordering uses the quantiser
-            _, idx = jax.lax.top_k(-sc.ravel(), beam)
+            with jax.named_scope("beam_pick"):
+                # scarlint: ignore[SL004] -- beam-stage ordering deliberately
+                # mirrors BeamEngine.combine's unquantised f64 argsort bit-
+                # for-bit; only the per-model pool ordering uses the quantiser
+                _, idx = jax.lax.top_k(-sc.ravel(), beam)
             return ((idx // n_s).astype(jnp.int32),
                     (idx % n_s).astype(jnp.int32), total)
 
@@ -249,7 +250,8 @@ def beam_scan(pool, full, *, beam: int, metric: str, max_exp: int,
           f_words, f_lat, f_e, sizes, keeps)
     if not presorted:
         xs = xs + (f_key,)
-    _, ys = jax.lax.scan(stage, carry0, xs)
+    with jax.named_scope("beam_scan"):
+        _, ys = jax.lax.scan(stage, carry0, xs)
     return ys
 
 
@@ -398,57 +400,66 @@ def fused_program(inputs, *, modes, pkg, mcm_cols: int, n_active: int,
                        pipelined=pipelined, has_prev=has_prev,
                        congestion=congestion,
                        noc=noc if congestion else None)
-        if congestion:
-            wp, wd = route_wait_tables(jnp, bg * inv_bw, mcm_rows, mcm_cols)
-            args = args[:11] + (wp, wd)
-        lat, energy = traceable_scores(args, statics, use_kernel=use_kernel,
-                                       interpret=interpret)
+        with jax.named_scope("score"):
+            if congestion:
+                wp, wd = route_wait_tables(jnp, bg * inv_bw, mcm_rows,
+                                           mcm_cols)
+                args = args[:11] + (wp, wd)
+            lat, energy = traceable_scores(args, statics,
+                                           use_kernel=use_kernel,
+                                           interpret=interpret)
         b_pad = lat.shape[0]
         valid = jnp.arange(b_pad) < n_real
-        # the host ordering contract (sched.build_candidates): stable sort
-        # on (tier, score quantised to the shared grain)
-        qs = quantize_scores_jax(metric_score(lat, energy, metric),
-                                 sig=SCORE_SIG)
-        key = _order_key(qs, tiers, valid)
+        with jax.named_scope("order"):
+            # the host ordering contract (sched.build_candidates): stable
+            # sort on (tier, score quantised to the shared grain)
+            qs = quantize_scores_jax(metric_score(lat, energy, metric),
+                                     sig=SCORE_SIG)
+            key = _order_key(qs, tiers, valid)
         if congestion:
             # greedy best = host lexsort rank 0 (argmin of the packed key
             # breaks exact ties by enumeration order, like the stable sort)
             bg = bg + _cand_link_bytes(args, jnp.argmin(key), rows=mcm_rows,
                                        cols=mcm_cols, has_prev=has_prev)
 
-        def tier_top(tier_id, width):
-            neg = jnp.where(valid & (tiers == tier_id), -qs, -jnp.inf)
-            vals, idx = jax.lax.top_k(neg, min(width, b_pad))
-            pad = width - idx.shape[0]
-            return (jnp.pad(idx.astype(jnp.int32), (0, pad)),
-                    jnp.pad(vals > -jnp.inf, (0, pad)))
+        with jax.named_scope("order"):
+            def tier_top(tier_id, width):
+                neg = jnp.where(valid & (tiers == tier_id), -qs, -jnp.inf)
+                vals, idx = jax.lax.top_k(neg, min(width, b_pad))
+                pad = width - idx.shape[0]
+                return (jnp.pad(idx.astype(jnp.int32), (0, pad)),
+                        jnp.pad(vals > -jnp.inf, (0, pad)))
 
-        i0, ok0 = tier_top(0, t0)
-        i1, ok1 = tier_top(1, t1)
-        p_idx = jnp.concatenate([i0, i1])
-        p_valid = jnp.concatenate([ok0, ok1])
-        lat_v = jnp.where(valid, lat, jnp.inf)
-        e_v = jnp.where(valid, energy, jnp.inf)
-        pools.append((
-            jnp.where(p_valid[:, None], words[p_idx], 0),
-            jnp.where(p_valid, lat_v[p_idx], jnp.inf),
-            jnp.where(p_valid, e_v[p_idx], jnp.inf),
-            p_idx, p_valid,
-            jnp.sum(valid & (tiers == 0), dtype=jnp.int32) > t0))
-        pad = n_pad - b_pad
-        fulls.append((
-            jnp.pad(words, ((0, pad), (0, 0))),
-            jnp.pad(lat_v, (0, pad), constant_values=np.inf),
-            jnp.pad(e_v, (0, pad), constant_values=np.inf),
-            jnp.pad(key, (0, pad), constant_values=_KEY_INVALID)))
-        mlats.append(jnp.min(lat_v))
+            i0, ok0 = tier_top(0, t0)
+            i1, ok1 = tier_top(1, t1)
+            p_idx = jnp.concatenate([i0, i1])
+            p_valid = jnp.concatenate([ok0, ok1])
+            lat_v = jnp.where(valid, lat, jnp.inf)
+            e_v = jnp.where(valid, energy, jnp.inf)
+            pools.append((
+                jnp.where(p_valid[:, None], words[p_idx], 0),
+                jnp.where(p_valid, lat_v[p_idx], jnp.inf),
+                jnp.where(p_valid, e_v[p_idx], jnp.inf),
+                p_idx, p_valid,
+                jnp.sum(valid & (tiers == 0), dtype=jnp.int32) > t0))
+            pad = n_pad - b_pad
+            fulls.append((
+                jnp.pad(words, ((0, pad), (0, 0))),
+                jnp.pad(lat_v, (0, pad), constant_values=np.inf),
+                jnp.pad(e_v, (0, pad), constant_values=np.inf),
+                jnp.pad(key, (0, pad), constant_values=_KEY_INVALID)))
+            mlats.append(jnp.min(lat_v))
 
-    # model order by compute weight, largest min-latency first (the host
-    # engines' ``sorted(key=-min(lat))``; jnp.argsort is stable)
-    morder = jnp.argsort(-jnp.stack(mlats))
-    pool = tuple(jnp.stack([p[k] for p in pools])[morder] for k in range(6))
-    full = tuple(jnp.stack([f[k] for f in fulls])[morder] for k in range(4))
-    sizes = jnp.stack([jnp.asarray(i[3], jnp.int32) for i in inputs])[morder]
+    with jax.named_scope("order"):
+        # model order by compute weight, largest min-latency first (the
+        # host engines' ``sorted(key=-min(lat))``; jnp.argsort is stable)
+        morder = jnp.argsort(-jnp.stack(mlats))
+        pool = tuple(jnp.stack([p[k] for p in pools])[morder]
+                     for k in range(6))
+        full = tuple(jnp.stack([f[k] for f in fulls])[morder]
+                     for k in range(4))
+        sizes = jnp.stack([jnp.asarray(i[3], jnp.int32)
+                           for i in inputs])[morder]
     keeps = jnp.full((len(inputs),), keep, jnp.int32)
     ys = beam_scan(pool, full[:3] + (full[3], sizes, keeps), beam=beam,
                    metric=metric, max_exp=max_exp, t0_width=t0,

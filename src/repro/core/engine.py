@@ -54,6 +54,12 @@ _MASK64 = (1 << 64) - 1
 # stochastic chains engine feeds these.
 _ANNEAL_PROPOSED = obs.counter("engine.anneal.moves_proposed")
 _ANNEAL_ACCEPTED = obs.counter("engine.anneal.moves_accepted")
+# What each fused window program is sent: real candidate rows, the rows of
+# the pool axis that hold them (the bucket ``n_pad`` per model) and the bytes
+# of every array passed to ``device_search.fused_program``.
+_ROWS_REAL = obs.counter("engine.window.rows_real")
+_ROWS_PADDED = obs.counter("engine.window.rows_padded")
+_UPLOAD_BYTES = obs.counter("engine.window.upload_bytes")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -559,33 +565,41 @@ class DeviceBeamEngine:
         from .sched import assemble_candidates
         from .segmentation import top_k_segmentations
 
-        alloc = provision(db, mcm.class_counts(), ranges, mcm.n_chiplets,
-                          metric=cfg.metric,
-                          max_nodes_per_model=cfg.max_nodes_per_model)
+        # leaf spans (cat ``engine``) cover the window's host stages, so a
+        # profiler trace names each of the device's idle gaps
+        with obs.span("provision", cat="engine", models=len(ranges)):
+            alloc = provision(db, mcm.class_counts(), ranges, mcm.n_chiplets,
+                              metric=cfg.metric,
+                              max_nodes_per_model=cfg.max_nodes_per_model)
         n_active = len(ranges)
+        use_kernel = self.uses_kernels()
         inputs, modes, built = [], [], []
         for mi, (s, e) in sorted(ranges.items()):
-            segs = top_k_segmentations(db, mcm, s, e, alloc[mi],
-                                       k=cfg.seg_top_k, cap=cfg.seg_cap,
-                                       metric=cfg.metric)
-            use_kernel = self.uses_kernels()
-            cand, tiers, (words, chips, seg_arr) = assemble_candidates(
-                mcm, mi, (s, e), segs, prev_end.get(mi),
-                path_cap=cfg.path_cap, frontier_cap=cfg.frontier_cap,
-                need_seg_id=use_kernel)
-            # congestion: ship full-shape zero wait tables; fused_program
-            # substitutes the traced, bg-derived tables in their place
-            args, statics, n_real = pack_candidates(
-                db, mcm, cand, n_active, prev_end=prev_end.get(mi),
-                pad_b=EVAL_BLOCK_B, dense=use_kernel,
-                comm_model=self.comm_model)
-            w32 = ds.split_words_u32(words)
-            t32 = tiers.astype(np.int32)
-            pad = args[5].shape[0] - n_real          # chips are [B_pad, S]
-            if pad:
-                w32 = np.concatenate(
-                    [w32, np.zeros((pad, w32.shape[1]), np.uint32)])
-                t32 = np.concatenate([t32, np.zeros(pad, np.int32)])
+            with obs.span("segment", cat="engine", model=mi):
+                segs = top_k_segmentations(db, mcm, s, e, alloc[mi],
+                                           k=cfg.seg_top_k, cap=cfg.seg_cap,
+                                           metric=cfg.metric)
+            with obs.span("assemble", cat="engine", model=mi) as sp:
+                cand, tiers, (words, chips, seg_arr) = assemble_candidates(
+                    mcm, mi, (s, e), segs, prev_end.get(mi),
+                    path_cap=cfg.path_cap, frontier_cap=cfg.frontier_cap,
+                    need_seg_id=use_kernel)
+                sp.set(cands=int(cand.seg_id.shape[0]))
+            with obs.span("pack", cat="engine", model=mi) as sp:
+                # congestion: ship full-shape zero wait tables;
+                # fused_program substitutes the traced, bg-derived tables
+                args, statics, n_real = pack_candidates(
+                    db, mcm, cand, n_active, prev_end=prev_end.get(mi),
+                    pad_b=EVAL_BLOCK_B, dense=use_kernel,
+                    comm_model=self.comm_model)
+                w32 = ds.split_words_u32(words)
+                t32 = tiers.astype(np.int32)
+                pad = args[5].shape[0] - n_real      # chips are [B_pad, S]
+                if pad:
+                    w32 = np.concatenate(
+                        [w32, np.zeros((pad, w32.shape[1]), np.uint32)])
+                    t32 = np.concatenate([t32, np.zeros(pad, np.int32)])
+                sp.set(rows=int(w32.shape[0]))
             inputs.append((args, w32, t32, np.int32(n_real)))
             modes.append((statics["pipelined"], statics["has_prev"]))
             built.append((cand, chips, seg_arr))
@@ -594,45 +608,54 @@ class DeviceBeamEngine:
         keep = int(cfg.keep_per_model)
         t0, t1 = ds.pool_widths(keep)
         congestion = self.comm_model == "congestion"
-        ds.note_program(
-            "fused",
-            (tuple(tuple(a.shape for a in i[0]) + (i[1].shape,) for i in
-                   inputs), tuple(modes), n_active, n_pad, self.beam, keep,
-             metric, self.max_expansions, t0, t1, self.uses_kernels(),
-             self.interpret, congestion))
-        out = ds.fused_program(
-            tuple(inputs), modes=tuple(modes), pkg=mcm.pkg,
-            mcm_cols=mcm.cols, n_active=n_active, n_pad=n_pad,
-            beam=self.beam, keep=keep, metric=metric,
-            max_exp=self.max_expansions, t0=t0, t1=t1,
-            use_kernel=self.uses_kernels(), interpret=self.interpret,
-            mcm_rows=mcm.rows, congestion=congestion,
-            noc=mcm.noc if congestion else None)
+        with obs.span("dispatch", cat="engine", n_pad=n_pad):
+            shapes = tuple(tuple(a.shape for a in i[0]) + (i[1].shape,)
+                           for i in inputs)
+            _ROWS_REAL.inc(sum(int(i[3]) for i in inputs))
+            _ROWS_PADDED.inc(n_pad * len(inputs))
+            _UPLOAD_BYTES.inc(sum(a.nbytes for i in inputs
+                                  for a in i[0] + i[1:]))
+            ds.note_program(
+                "fused",
+                (shapes, tuple(modes), n_active, n_pad, self.beam, keep,
+                 metric, self.max_expansions, t0, t1, use_kernel,
+                 self.interpret, congestion))
+            out = ds.fused_program(
+                tuple(inputs), modes=tuple(modes), pkg=mcm.pkg,
+                mcm_cols=mcm.cols, n_active=n_active, n_pad=n_pad,
+                beam=self.beam, keep=keep, metric=metric,
+                max_exp=self.max_expansions, t0=t0, t1=t1,
+                use_kernel=use_kernel, interpret=self.interpret,
+                mcm_rows=mcm.rows, congestion=congestion,
+                noc=mcm.noc if congestion else None)
         # the single counted host transfer of the whole window search
-        (morder, parents, cands, tlats, tes,
-         counts, fails) = launch_platform.device_fetch(out)
-        failed = np.flatnonzero(fails)
-        if failed.size:
-            cand = built[int(morder[int(failed[0])])][0]
-            _raise_no_disjoint(cand.model_idx, cand.seg_id.shape[0])
-        picks = _backtrack(parents, cands)
-        plans = []
-        for st in range(len(built)):
-            cand, chips, seg_arr = built[int(morder[st])]
-            # the scan emits assembled-candidate row indices directly
-            r = int(picks[st])
-            ns = int(cand.n_segs[r])
-            plans.append(ModelWindowPlan(
-                model_idx=cand.model_idx, start=cand.start, end=cand.end,
-                seg_ends=tuple(int(x) for x in seg_arr[r, :ns]),
-                chiplets=tuple(int(c) for c in chips[r, :ns]),
-                pipelined=True))
-        plan = WindowPlan(plans=tuple(sorted(plans,
-                                             key=lambda p: p.model_idx)))
-        result = evaluate_window(db, mcm, plan, prev_end, validate=True,
-                                 comm_model=self.comm_model)
+        with obs.span("fetch", cat="engine"):
+            (morder, parents, cands, tlats, tes,
+             counts, fails) = launch_platform.device_fetch(out)
+        with obs.span("rescore", cat="engine"):
+            failed = np.flatnonzero(fails)
+            if failed.size:
+                cand = built[int(morder[int(failed[0])])][0]
+                _raise_no_disjoint(cand.model_idx, cand.seg_id.shape[0])
+            picks = _backtrack(parents, cands)
+            plans = []
+            for st in range(len(built)):
+                cand, chips, seg_arr = built[int(morder[st])]
+                # the scan emits assembled-candidate row indices directly
+                r = int(picks[st])
+                ns = int(cand.n_segs[r])
+                plans.append(ModelWindowPlan(
+                    model_idx=cand.model_idx, start=cand.start, end=cand.end,
+                    seg_ends=tuple(int(x) for x in seg_arr[r, :ns]),
+                    chiplets=tuple(int(c) for c in chips[r, :ns]),
+                    pipelined=True))
+            plan = WindowPlan(plans=tuple(sorted(plans,
+                                                 key=lambda p: p.model_idx)))
+            result = evaluate_window(db, mcm, plan, prev_end, validate=True,
+                                     comm_model=self.comm_model)
+            explored = _explored(tlats, tes, counts)
         return WindowSearchResult(plan=plan, result=result,
-                                  explored=_explored(tlats, tes, counts))
+                                  explored=explored)
 
 
 # ---------------------------------------------------------------------------

@@ -45,12 +45,9 @@ BACKENDS = ("auto", "numpy", "jax_ref", "pallas")
 # Shape-bucket compile accounting: the jax eval path recompiles once per
 # distinct (backend, shapes, statics) signature; counting *new* signatures
 # at the call site is deterministic and jax-version-independent, unlike
-# polling jit cache internals.  `evaluator.eval_calls.<backend>` counts
-# every dispatch per resolved backend.
+# polling jit cache internals.
 _RECOMPILES = obs.counter("evaluator.jit_recompiles")
 _SEEN_SIGNATURES: set[tuple] = set()
-_EVAL_CALLS = {b: obs.counter(f"evaluator.eval_calls.{b}")
-               for b in ("numpy", "jax_ref", "pallas")}
 
 # Kernel batch block; pack_candidates pads B to a multiple of this.
 EVAL_BLOCK_B = 128
@@ -124,7 +121,6 @@ def eval_candidates(db: CostDB, mcm: MCM, cand: BatchedModelCandidates,
     """
     B, Lw = cand.seg_id.shape
     resolved = resolve_backend(backend, work=B * Lw)
-    _EVAL_CALLS[resolved].inc()
     if resolved == "numpy":
         with obs.span("eval_candidates", cat="evaluator", backend="numpy",
                       batch=B, layers=Lw):

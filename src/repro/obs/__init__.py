@@ -24,6 +24,8 @@ Typical use::
     from repro import obs
 
     obs.enable()                      # or SCAR_TRACE=1 in the environment
+    # obs.enable(annotate=jax.profiler.TraceAnnotation) also mirrors every
+    # span into a running JAX profiler trace as a ``scar.<name>`` annotation
     outcome = schedule(sc, mcm, cfg)
     obs.chrome_trace("trace.json")    # -> load in ui.perfetto.dev
     print(obs.format_summary())
@@ -34,7 +36,7 @@ Span taxonomy and counter naming conventions: ``docs/observability.md``.
 from __future__ import annotations
 
 import os
-from typing import Optional
+from typing import Any, Callable, Optional
 
 from . import export as _export
 from . import registry
@@ -53,11 +55,20 @@ __all__ = ["Counter", "Gauge", "Span", "Tracer", "bench_dump",
 _TRACER: Optional[Tracer] = None
 
 
-def enable() -> Tracer:
-    """Install (or return the already-installed) recording tracer."""
+def enable(annotate: Optional[Callable[[str], Any]] = None) -> Tracer:
+    """Install (or return the already-installed) recording tracer.
+
+    ``annotate``: a context-manager factory, e.g.
+    ``jax.profiler.TraceAnnotation``; while it is set every span also opens
+    ``annotate("scar." + name)``, so a profiler trace shows the program's
+    spans on its own clock.  Given to an installed tracer, it replaces that
+    tracer's factory.
+    """
     global _TRACER
     if _TRACER is None:
-        _TRACER = Tracer()
+        _TRACER = Tracer(annotate)
+    elif annotate is not None:
+        _TRACER.annotate = annotate
     return _TRACER
 
 
@@ -94,7 +105,7 @@ def reset(counters_too: bool = True) -> None:
     """Drop recorded spans (if tracing) and optionally zero the registry."""
     global _TRACER
     if _TRACER is not None:
-        _TRACER = Tracer()
+        _TRACER = Tracer(_TRACER.annotate)
     if counters_too:
         registry.reset()
 

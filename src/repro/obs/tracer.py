@@ -20,6 +20,11 @@ on the finished record.  Records carry monotonic wall time
 recording process id and a dense per-process thread id, plus the id of the
 enclosing span — everything the exporters need for Chrome-trace nesting and
 per-phase attribution.
+
+A tracer built with ``annotate`` (a context-manager factory such as
+``jax.profiler.TraceAnnotation``) also opens ``annotate("scar." + name)``
+around every span, so a profiler trace carries the program's spans on its
+own clock.  The module stays stdlib-only: the caller passes the factory.
 """
 from __future__ import annotations
 
@@ -27,8 +32,12 @@ import itertools
 import os
 import threading
 import time
+from typing import Any, Callable, Optional
 
 __all__ = ["NULL_SPAN", "Span", "Tracer"]
+
+# Prefix of the profiler annotations mirrored from spans.
+ANNOTATION_PREFIX = "scar."
 
 
 class _NullSpan:
@@ -54,7 +63,7 @@ class Span:
     """One live span; appends its finished record to the tracer on exit."""
 
     __slots__ = ("tracer", "name", "cat", "attrs", "sid", "parent",
-                 "t0", "c0")
+                 "t0", "c0", "note")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  attrs: dict) -> None:
@@ -76,9 +85,17 @@ class Span:
         stack.append(self.sid)
         self.c0 = time.thread_time()
         self.t0 = time.perf_counter()
+        # a mirrored span's time holds its annotation, so a parent's self
+        # time does not grow with its children's annotations
+        self.note = (None if tr.annotate is None
+                     else tr.annotate(ANNOTATION_PREFIX + self.name))
+        if self.note is not None:
+            self.note.__enter__()
         return self
 
     def __exit__(self, *exc: object) -> bool:
+        if self.note is not None:
+            self.note.__exit__(*exc)
         t1 = time.perf_counter()
         c1 = time.thread_time()
         tr = self.tracer
@@ -103,10 +120,14 @@ class Tracer:
     and zero-duration instant records (``dur`` absent).  Times are relative
     to ``t0`` (``perf_counter`` at construction); ``wall0`` (``time.time``
     at construction) lets snapshots from other processes be shifted onto
-    this tracer's time base when merged.
+    this tracer's time base when merged.  ``annotate``, when given, is the
+    context-manager factory every span also opens (see the module
+    docstring).
     """
 
-    def __init__(self) -> None:
+    def __init__(self, annotate: Optional[Callable[[str], Any]] = None
+                 ) -> None:
+        self.annotate = annotate
         self.t0 = time.perf_counter()
         self.wall0 = time.time()
         self.pid = os.getpid()

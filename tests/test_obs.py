@@ -9,6 +9,7 @@ never absolute values accumulated by other tests.
 """
 import json
 
+import jax
 import pytest
 
 from repro import obs
@@ -20,6 +21,11 @@ from repro.launch import platform as lp
 from repro.obs.tracer import NULL_SPAN, Tracer
 
 _SMALL = SearchConfig(path_cap=32, seg_cap=64, n_splits=2)
+_FUSED = SearchConfig(algo="beam_jax", path_cap=32, seg_cap=64, n_splits=2)
+# the span names ``DeviceBeamEngine.combine_window`` nests under itself
+_WINDOW_LEAVES = ("provision", "segment", "assemble", "pack", "dispatch",
+                  "fetch", "rescore")
+_PER_MODEL = ("segment", "assemble", "pack")
 
 
 @pytest.fixture(autouse=True)
@@ -28,6 +34,8 @@ def _restore_tracing_state():
     yield
     if not was:
         obs.disable()
+    elif obs.tracer() is not None:
+        obs.tracer().annotate = None
 
 
 def _plans(outcome):
@@ -147,21 +155,156 @@ def test_device_program_recompile_counter_counts_first_seen_only():
 
 # ---------------------- plan invariance --------------------------------------
 
-@pytest.mark.parametrize("scenario,pattern,n_pe", [
-    ("xr8_outdoors", "het_sides", 256),
-    ("dc1_lms", "het_cross", 4096),
+@pytest.mark.parametrize("scenario,pattern,n_pe,cfg,annotate", [
+    pytest.param("xr8_outdoors", "het_sides", 256, _SMALL, None,
+                 id="xr8_outdoors-het_sides-256"),
+    pytest.param("dc1_lms", "het_cross", 4096, _SMALL, None,
+                 id="dc1_lms-het_cross-4096"),
+    pytest.param("dc4_lms_seg_image", "het_sides", 4096, _FUSED,
+                 jax.profiler.TraceAnnotation,
+                 id="dc4_lms_seg_image-het_sides-4096-beam_jax-mirror"),
 ])
-def test_tracing_is_plan_invariant(scenario, pattern, n_pe):
+def test_tracing_is_plan_invariant(scenario, pattern, n_pe, cfg, annotate):
     sc = get_scenario(scenario)
     mcm = make_mcm(pattern, rows=3, cols=3, n_pe=n_pe)
     obs.disable()
-    off = _plans(schedule(sc, mcm, _SMALL))
-    obs.enable()
-    on = _plans(schedule(sc, mcm, _SMALL))
+    off = _plans(schedule(sc, mcm, cfg))
+    obs.enable(annotate=annotate)
+    on = _plans(schedule(sc, mcm, cfg))
     assert on == off                       # bit-identical under tracing
 
 
+# ---------------------- profiler mirror --------------------------------------
+
+class _FakeAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``; logs enter/exit."""
+
+    log: list = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        self.log.append(("enter", self.name))
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name))
+
+
+def _xr8_schedule():
+    schedule(get_scenario("xr8_outdoors"),
+             make_mcm("het_sides", rows=3, cols=3, n_pe=256), _SMALL)
+
+
+def test_span_mirror_opens_one_annotation_per_span():
+    _FakeAnnotation.log = []
+    obs.disable()
+    obs.enable()
+    obs.enable(annotate=_FakeAnnotation)   # an installed tracer takes it
+    obs.reset(counters_too=False)          # a fresh tracer keeps the factory
+    with obs.span("outer", cat="test"):
+        with obs.span("inner", cat="test"):
+            pass
+    _xr8_schedule()
+    spans = [e["name"] for e in obs.tracer().events if "dur" in e]
+    log = _FakeAnnotation.log
+    assert spans[:2] == ["inner", "outer"]
+    assert log[:4] == [("enter", "scar.outer"), ("enter", "scar.inner"),
+                       ("exit", "scar.inner"), ("exit", "scar.outer")]
+    for kind in ("enter", "exit"):
+        names = sorted(n for k, n in log if k == kind)
+        assert names == sorted("scar." + n for n in spans), kind
+
+
+@pytest.mark.parametrize("state", ["disabled", "no_factory"])
+def test_span_mirror_is_off_without_tracer_or_factory(state):
+    _FakeAnnotation.log = []
+    obs.disable()
+    if state == "no_factory":
+        obs.enable()
+        obs.reset(counters_too=False)
+    else:
+        # a factory dies with its tracer: enabling again brings none back
+        obs.enable(annotate=_FakeAnnotation)
+        obs.disable()
+    _xr8_schedule()
+    with obs.span("outer", cat="test"):
+        pass
+    assert _FakeAnnotation.log == []
+    assert obs.enabled() == (state == "no_factory")
+
+
+def test_span_mirror_closes_annotation_when_span_raises():
+    _FakeAnnotation.log = []
+    obs.disable()
+    obs.enable(annotate=_FakeAnnotation)
+    with pytest.raises(ValueError):
+        with obs.span("failing", cat="test"):
+            raise ValueError("boom")
+    assert _FakeAnnotation.log == [("enter", "scar.failing"),
+                                   ("exit", "scar.failing")]
+
+
 # ---------------------- pipeline instrumentation -----------------------------
+
+def _fused_dc4():
+    schedule(get_scenario("dc4_lms_seg_image"),
+             make_mcm("het_sides", rows=3, cols=3, n_pe=4096), _FUSED)
+
+
+def test_fused_window_leaf_spans_nest_under_combine_window():
+    obs.disable()
+    obs.enable()
+    obs.reset(counters_too=False)
+    _fused_dc4()
+    evs = [e for e in obs.tracer().events if "dur" in e]
+    windows = {e["sid"]: e for e in evs if e["name"] == "combine_window"}
+    assert len(windows) == _FUSED.n_splits + 1
+    leaves = [e for e in evs if e["name"] in _WINDOW_LEAVES]
+    assert all(e["parent"] in windows and e["cat"] == "engine"
+               for e in leaves)
+    for sid, win in windows.items():
+        mine = [e["name"] for e in leaves if e["parent"] == sid]
+        n_models = win["args"]["models"]
+        for name in _WINDOW_LEAVES:
+            want = n_models if name in _PER_MODEL else 1
+            assert mine.count(name) == want, (name, mine)
+        # the leaves run one after another inside their window
+        inner = sum(e["dur"] for e in leaves if e["parent"] == sid)
+        assert inner <= win["dur"]
+    assembled = [e["args"] for e in leaves if e["name"] == "assemble"]
+    assert all(a["cands"] > 0 for a in assembled)
+
+
+def test_fused_window_counters_match_the_programs_sent():
+    names = ("engine.window.rows_real", "engine.window.rows_padded",
+             "engine.window.upload_bytes")
+    before = {n: obs.registry.value(n) for n in names}
+    obs.disable()
+    obs.enable()
+    obs.reset(counters_too=False)
+    _fused_dc4()
+    real, padded, upload = (obs.registry.value(n) - before[n]
+                            for n in names)
+    evs = [e for e in obs.tracer().events if "dur" in e]
+    windows = {e["sid"]: e["args"]["models"] for e in evs
+               if e["name"] == "combine_window"}
+    cands = sum(e["args"]["cands"] for e in evs if e["name"] == "assemble")
+    rows = sum(e["args"]["n_pad"] * windows[e["parent"]] for e in evs
+               if e["name"] == "dispatch")
+    assert 0 < real <= padded
+    assert real == cands                   # every real candidate, once
+    assert padded == rows                  # the pool axis, every model
+    packed = sum(e["args"]["rows"] for e in evs if e["name"] == "pack")
+    assert upload > 4 * packed             # at least the tier of each row
+
+
+def test_schedule_without_tracing_still_counts_window_rows():
+    before = obs.registry.value("engine.window.rows_real")
+    obs.disable()
+    _fused_dc4()
+    assert obs.registry.value("engine.window.rows_real") > before
+
 
 def test_schedule_emits_span_taxonomy():
     sc = get_scenario("xr8_outdoors")
