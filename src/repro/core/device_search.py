@@ -39,13 +39,15 @@ order-isomorphic to their uint32 patterns, so the pool key packs
 ``tier << 31 | bitcast(quantised score)`` and per-tier ``top_k`` returns
 host-order prefixes with the host's lowest-index tie rule.
 
-Static program keys: per-model shapes + mode flags, package params, mesh
-cols, ``n_active``, the bucketed full-pool width, beam width, keep, metric
-and the pool widths — a handful of compiles per (mesh, window shape);
-candidate *counts* and anchors are traced and do not recompile.
+Static program keys: the staging layout (per-model shapes) + mode flags,
+package params, mesh cols, ``n_active``, the bucketed full-pool width, beam
+width, keep, metric and the pool widths — a handful of compiles per (mesh,
+window shape); candidate *counts* and anchors are traced and do not
+recompile.
 """
 from __future__ import annotations
 
+import math
 from functools import partial
 
 import jax
@@ -128,6 +130,44 @@ def split_words_u32(words: np.ndarray) -> np.ndarray:
     out[:, 0::2] = lo
     out[:, 1::2] = hi
     return out
+
+
+def stage_window(inputs):
+    """Every array of ``inputs`` in one host ``uint32`` buffer, bitwise.
+
+    ``inputs`` is a pytree whose leaves are 4-byte numpy arrays or scalars
+    (float32, int32, uint32).  Returns ``(buf, layout)``: ``buf`` holds the
+    leaves' words back to back (one host copy, so the window makes one
+    host-to-device transfer instead of one per array), and ``layout`` —
+    the tree structure and, per leaf, its word offset, shape and dtype —
+    is the static key ``unstage_window`` rebuilds the leaves from.  It
+    depends on the leaves' shapes and dtypes only, never their values.
+    """
+    leaves, treedef = jax.tree.flatten(inputs)
+    words, slots, off = [], [], 0
+    for leaf in leaves:
+        a = np.asarray(leaf)
+        if a.dtype.itemsize != 4:
+            raise TypeError(f"a staged array must be 4 bytes wide, "
+                            f"got {a.dtype}")
+        words.append(a.reshape(-1).view(np.uint32))
+        slots.append((off, a.shape, a.dtype))
+        off += a.size
+    return np.concatenate(words), (treedef, tuple(slots))
+
+
+def unstage_window(buf, layout):
+    """In-jit inverse of ``stage_window``: the same pytree, bit for bit.
+
+    Each leaf is a static slice of ``buf``, bitcast to its dtype and
+    reshaped; values stay traced, so a new anchor or candidate count
+    never recompiles.
+    """
+    treedef, slots = layout
+    return jax.tree.unflatten(treedef, [
+        jax.lax.bitcast_convert_type(buf[off:off + math.prod(shape)],
+                                     dtype).reshape(shape)
+        for off, shape, dtype in slots])
 
 
 def beam_scan(pool, full, *, beam: int, metric: str, max_exp: int,
@@ -357,22 +397,23 @@ def _order_key(qs, tiers, valid):
     return jnp.where(valid, key, _KEY_INVALID)
 
 
-@partial(jax.jit, static_argnames=("modes", "pkg", "mcm_cols", "n_active",
-                                   "n_pad", "beam", "keep", "metric",
-                                   "max_exp", "t0", "t1", "use_kernel",
-                                   "interpret", "mcm_rows", "congestion",
-                                   "noc"))
-def fused_program(inputs, *, modes, pkg, mcm_cols: int, n_active: int,
+@partial(jax.jit, static_argnames=("layout", "modes", "pkg", "mcm_cols",
+                                   "n_active", "n_pad", "beam", "keep",
+                                   "metric", "max_exp", "t0", "t1",
+                                   "use_kernel", "interpret", "mcm_rows",
+                                   "congestion", "noc"))
+def fused_program(buf, *, layout, modes, pkg, mcm_cols: int, n_active: int,
                   n_pad: int, beam: int, keep: int, metric: str,
                   max_exp: int, t0: int, t1: int, use_kernel: bool,
                   interpret: bool, mcm_rows: int = 0,
                   congestion: bool = False, noc=None):
     """The whole window search as one device program (see module docstring).
 
-    ``inputs``: per model ``(eval_args, words [B, 2W] uint32,
-    tiers [B] int32, n_real)`` where ``eval_args`` is
-    ``scar_eval.pack_candidates`` output and ``B`` its padded batch;
-    ``modes``: per model ``(pipelined, has_prev)`` static flags.  Returns
+    ``buf``, ``layout``: the window's inputs as ``stage_window`` staged
+    them — per model ``(eval_args, words [B, 2W] uint32, tiers [B] int32,
+    n_real)`` where ``eval_args`` is ``scar_eval.pack_candidates`` output
+    and ``B`` its padded batch; ``modes``: per model
+    ``(pipelined, has_prev)`` static flags.  Returns
     ``(model_order,) + beam_scan ys`` — the ys candidate indices address
     the *assembled* candidate batches directly, so the host rebuilds the
     window plan from one fetch.
@@ -387,6 +428,7 @@ def fused_program(inputs, *, modes, pkg, mcm_cols: int, n_active: int,
     accumulated into ``bg`` for the models that follow.  ``mcm_rows`` and
     the static ``noc`` link config are only consulted in this mode.
     """
+    inputs = unstage_window(buf, layout)
     if congestion:
         n_h = mcm_rows * (mcm_cols - 1)
         inv_bw = np.zeros(n_h + (mcm_rows - 1) * mcm_cols, np.float32)

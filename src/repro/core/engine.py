@@ -55,11 +55,12 @@ _MASK64 = (1 << 64) - 1
 _ANNEAL_PROPOSED = obs.counter("engine.anneal.moves_proposed")
 _ANNEAL_ACCEPTED = obs.counter("engine.anneal.moves_accepted")
 # What each fused window program is sent: real candidate rows, the rows of
-# the pool axis that hold them (the bucket ``n_pad`` per model) and the bytes
-# of every array passed to ``device_search.fused_program``.
+# the pool axis that hold them (the bucket ``n_pad`` per model), the bytes
+# of the window's staged inputs and the host-to-device copies that carry them.
 _ROWS_REAL = obs.counter("engine.window.rows_real")
 _ROWS_PADDED = obs.counter("engine.window.rows_padded")
 _UPLOAD_BYTES = obs.counter("engine.window.upload_bytes")
+_UPLOADS = obs.counter("engine.window.uploads")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -556,6 +557,8 @@ class DeviceBeamEngine:
                         prev_end: dict[int, int],
                         metric: str) -> WindowSearchResult:
         # local imports: sched/scheduler import this module at module level
+        import jax
+
         from repro.kernels.scar_eval import pack_candidates
         from repro.launch import platform as launch_platform
 
@@ -613,17 +616,20 @@ class DeviceBeamEngine:
                            for i in inputs)
             _ROWS_REAL.inc(sum(int(i[3]) for i in inputs))
             _ROWS_PADDED.inc(n_pad * len(inputs))
-            _UPLOAD_BYTES.inc(sum(a.nbytes for i in inputs
-                                  for a in i[0] + i[1:]))
             ds.note_program(
                 "fused",
                 (shapes, tuple(modes), n_active, n_pad, self.beam, keep,
                  metric, self.max_expansions, t0, t1, use_kernel,
                  self.interpret, congestion))
+            # the window's one host-to-device copy: a copy costs a fixed
+            # overhead on the chip, whatever its size
+            buf, layout = ds.stage_window(tuple(inputs))
+            _UPLOAD_BYTES.inc(buf.nbytes)
+            _UPLOADS.inc()
             out = ds.fused_program(
-                tuple(inputs), modes=tuple(modes), pkg=mcm.pkg,
-                mcm_cols=mcm.cols, n_active=n_active, n_pad=n_pad,
-                beam=self.beam, keep=keep, metric=metric,
+                jax.device_put(buf), layout=layout, modes=tuple(modes),
+                pkg=mcm.pkg, mcm_cols=mcm.cols, n_active=n_active,
+                n_pad=n_pad, beam=self.beam, keep=keep, metric=metric,
                 max_exp=self.max_expansions, t0=t0, t1=t1,
                 use_kernel=use_kernel, interpret=self.interpret,
                 mcm_rows=mcm.rows, congestion=congestion,

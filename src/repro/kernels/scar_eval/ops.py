@@ -150,7 +150,10 @@ def pack_candidates(db, mcm, cand, n_active: int, prev_end=None,
     Returns ``(args, statics, B)``: positional arrays for ``evaluate``, the
     static keyword arguments (``pkg``/``mcm_cols``/``n_active``/
     ``pipelined``/``has_prev``/``congestion``/``noc``) and the real
-    (pre-padding) candidate count.
+    (pre-padding) candidate count.  ``args`` are host numpy arrays and
+    scalars, each 4 bytes wide (float32 or int32); placing them on the
+    device is left to the caller (the jitted ``evaluate`` does it per
+    call, the fused window search stages a whole window in one upload).
     ``pipelined=False`` selects the sequential (sum over segments) latency
     mode, matching ``eval_model_candidates(..., pipelined=False)``.
 
@@ -163,7 +166,7 @@ def pack_candidates(db, mcm, cand, n_active: int, prev_end=None,
     ``dense=False`` ships a ``[B, 1]`` placeholder in the ``seg_id`` slot —
     the per-layer segment ids are consumed only by the ``use_kernel=True``
     dense form, and at large path caps they are the batch's largest array
-    (``[B, Lw]``), so jax_ref callers skip that cast + host->device copy.
+    (``[B, Lw]``), so jax_ref callers skip that cast and its upload.
     """
     B, Lw = cand.seg_id.shape
     S = max(1, int(cand.n_segs.max()))           # shrink to per-batch max
@@ -202,12 +205,10 @@ def pack_candidates(db, mcm, cand, n_active: int, prev_end=None,
     else:
         wait_pair = np.zeros((1, 1), np.float32)
         wait_dram = np.zeros(1, np.float32)
-    args = tuple(jnp.asarray(a) for a in
-                 (lat_tab, e_tab, w_bytes, out_bytes, class_map, chips,
-                  seg_id, last, n_segs,
-                  np.float32(db.in_bytes[cand.start]),
-                  np.int32(prev_end if prev_end is not None else 0),
-                  wait_pair, wait_dram))
+    args = (lat_tab, e_tab, w_bytes, out_bytes, class_map, chips, seg_id,
+            last, n_segs, np.float32(db.in_bytes[cand.start]),
+            np.int32(prev_end if prev_end is not None else 0),
+            wait_pair, wait_dram)
     statics = dict(pkg=mcm.pkg, mcm_cols=mcm.cols, n_active=n_active,
                    pipelined=pipelined, has_prev=prev_end is not None,
                    congestion=congestion,

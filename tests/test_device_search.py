@@ -156,6 +156,103 @@ def test_fused_schedule_respects_env_override(monkeypatch):
     assert all(h.plan == d.plan for h, d in zip(host.windows, dev.windows))
 
 
+# ------------------------- one upload per window ----------------------------
+
+@pytest.mark.parametrize("pattern,comm_model", [("het_cb", "analytic"),
+                                                ("het_cross", "congestion")])
+def test_fused_schedule_uploads_once_per_window(monkeypatch, pattern,
+                                                comm_model):
+    """Each window stages its inputs in one buffer and copies it to the
+    device once: ``engine.window.uploads`` rises by one per window, as the
+    fetch count does."""
+    from repro import obs
+
+    monkeypatch.delenv("SCAR_SEARCH_BACKEND", raising=False)
+    uploads0, syncs0 = obs.registry.value("engine.window.uploads"), \
+        lp.sync_count()
+    dev = schedule(get_scenario("dc4_lms_seg_image"),
+                   make_mcm(pattern, n_pe=4096),
+                   SearchConfig(algo="beam_jax", comm_model=comm_model))
+    uploads = obs.registry.value("engine.window.uploads") - uploads0
+    assert uploads == len(dev.windows) == lp.sync_count() - syncs0
+
+
+def test_fused_program_call_makes_no_host_to_device_copy(monkeypatch):
+    """Every argument of the fused window program is already on the device
+    when it is called: with host-to-device transfers disallowed around the
+    call, a ``beam_jax`` schedule still runs and plans as the host beam."""
+    import jax
+
+    from repro.core import device_search as ds
+
+    monkeypatch.delenv("SCAR_SEARCH_BACKEND", raising=False)
+    program = ds.fused_program
+
+    def guarded(*args, **kwargs):
+        with jax.transfer_guard_host_to_device("disallow"):
+            return program(*args, **kwargs)
+
+    monkeypatch.setattr(ds, "fused_program", guarded)
+    sc = get_scenario("dc4_lms_seg_image")
+    mcm = make_mcm("het_cb", n_pe=4096)
+    host = schedule(sc, mcm, SearchConfig(algo="beam"))
+    dev = schedule(sc, mcm, SearchConfig(algo="beam_jax"))
+    assert [w.plan for w in dev.windows] == [w.plan for w in host.windows]
+
+
+@pytest.mark.parametrize("comm_model", ["analytic", "congestion"])
+@pytest.mark.parametrize("dense", [True, False])
+def test_staged_window_round_trips_bitwise(monkeypatch, dense, comm_model):
+    """One window's inputs, as the engine hands them to ``stage_window``,
+    come back from ``unstage_window`` inside a jit bit for bit, slot by
+    slot: float32 ``inf``, a negative int32 anchor and uint32 words with
+    the high bit set included."""
+    import jax
+
+    from repro.core import device_search as ds
+
+    class _Captured(Exception):
+        pass
+
+    staged = []
+    stage = ds.stage_window
+
+    def spy(inputs):
+        staged.append(inputs)
+        return stage(inputs)
+
+    def stop(*args, **kwargs):
+        raise _Captured
+
+    monkeypatch.delenv("SCAR_SEARCH_BACKEND", raising=False)
+    monkeypatch.setattr(DeviceBeamEngine, "uses_kernels", lambda self: dense)
+    monkeypatch.setattr(ds, "stage_window", spy)
+    monkeypatch.setattr(ds, "fused_program", stop)
+    with pytest.raises(_Captured):
+        schedule(get_scenario("dc4_lms_seg_image"),
+                 make_mcm("het_cross", n_pe=4096),
+                 SearchConfig(algo="beam_jax", comm_model=comm_model))
+    (args, words, tiers, n_real), *rest = staged[0]
+    lat_tab, words = args[0].copy(), words.copy()
+    lat_tab[0, 0] = np.inf
+    words[0, 0] = np.uint32(0x80000001)
+    args = (lat_tab,) + args[1:10] + (np.int32(-7),) + args[11:]
+    inputs = ((args, words, tiers, n_real), *rest)
+    assert (args[6].shape[1] > 1) == dense      # the dense seg_id slot
+
+    buf, layout = ds.stage_window(inputs)
+    assert buf.dtype == np.uint32
+    back = jax.jit(ds.unstage_window, static_argnums=1)(buf, layout)
+    assert jax.tree.structure(back) == jax.tree.structure(inputs)
+    leaves = jax.tree.leaves(inputs)
+    assert len(leaves) == 16 * len(inputs)
+    for want, got in zip(leaves, jax.tree.leaves(back)):
+        want, got = np.asarray(want), np.asarray(got)
+        assert got.dtype == want.dtype and got.shape == want.shape
+        assert got.tobytes() == want.tobytes()
+    assert buf.nbytes == sum(a.nbytes for a in map(np.asarray, leaves))
+
+
 # ------------------------- shared quantisation ------------------------------
 
 def test_quantize_scores_jax_matches_numpy():
