@@ -25,10 +25,38 @@ scan works from a per-model candidate *pool* — a prefix of the full
 (tier, quantised-score) candidate order — and falls back to the full pool
 under ``lax.cond`` only when some beam row found fewer than ``keep``
 disjoint candidates in the prefix.  Both branches implement the host
-``BeamEngine`` stage semantics exactly (keep-rank filter, row-major budget
-truncation, stable score/tie top-k); the pool branch is exact because the
-host keep filter only ever selects a row's first ``keep`` disjoint
-candidates, which the completeness predicate confines to the prefix.
+``BeamEngine`` stage semantics (keep-rank filter, row-major budget
+truncation, stable score/tie top-k); the pool branch equals the fallback
+because the host keep filter only ever selects a row's first ``keep``
+disjoint candidates, which the completeness predicate confines to the
+prefix.
+
+What that guarantees against the float64 host beam (the oracle) depends
+on the scores the scan is given.  ``protocol_program`` takes the host's
+float64 tables and returns its plans bit for bit, under every metric.
+``fused_program`` scores in float32, and the ordering contract it shares
+with the oracle has two parts:
+
+* the per-model pool order is (tier, score quantised to ``SCORE_SIG``),
+  then enumeration, as ``sched.build_candidates``.  The float32 score's
+  digits are rounded exactly (``quantize_scores_jax``), so only a
+  candidate whose float32 score itself falls on the other side of a
+  quantisation boundary from its float64 score sits one bucket off;
+* each beam stage orders on the unquantised score, equal scores in
+  row-major order, as ``BeamEngine``'s stable float64 argsort.  Segment
+  compute latencies are summed exactly (``kernels.scar_eval.split_exact``),
+  and energies as prefix-sum differences that telescope, so the float64
+  ties that placements built from the same layers make — one bottleneck
+  segment under ``metric="latency"``, where most windows end in a tie;
+  splits of the same layers on one class under energy or EDP — stay ties
+  in float32, and the tie rule picks the oracle's plan.  Two float64
+  scores closer than float32 rounding (~1e-7 relative) may still be
+  ordered otherwise.
+
+So the fused plans equal the oracle's except at such near-ties and
+quantisation boundaries, under each metric; the reported metrics are the
+float64 re-score of the plan picked.  ``engine.window.tied_picks`` counts
+the windows whose plan the tie rule decided.
 
 Why pools instead of sorting every candidate up front: XLA's CPU sort costs
 ~16 ms per 47k-candidate model while two per-tier ``lax.top_k`` passes cost
@@ -235,9 +263,11 @@ def beam_scan(pool, full, *, beam: int, metric: str, max_exp: int,
             sc = jnp.where(flat.reshape(beam, n_s),
                            metric_score(new_lat, new_e, metric), jnp.inf)
             with jax.named_scope("beam_pick"):
-                # scarlint: ignore[SL004] -- beam-stage ordering deliberately
-                # mirrors BeamEngine.combine's unquantised f64 argsort bit-
-                # for-bit; only the per-model pool ordering uses the quantiser
+                # Bit for bit in the float64 protocol form; in the float32
+                # fused form the oracle's ties of same-layer placements
+                # stay ties, nearer scores may not (module docstring).
+                # scarlint: ignore[SL004] -- mirrors BeamEngine.combine's
+                # unquantised stable argsort: equal scores row-major
                 _, idx = jax.lax.top_k(-sc.ravel(), beam)
             return ((idx // n_s).astype(jnp.int32),
                     (idx % n_s).astype(jnp.int32), total)

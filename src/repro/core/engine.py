@@ -61,6 +61,11 @@ _ROWS_REAL = obs.counter("engine.window.rows_real")
 _ROWS_PADDED = obs.counter("engine.window.rows_padded")
 _UPLOAD_BYTES = obs.counter("engine.window.upload_bytes")
 _UPLOADS = obs.counter("engine.window.uploads")
+# Windows the fused search picked a plan for, and those among them whose
+# best last-stage beam row shares its ordering score with another valid
+# row: windows whose plan the beam's tie rule decided.
+_PICKS = obs.counter("engine.window.picks")
+_TIED_PICKS = obs.counter("engine.window.tied_picks")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -276,6 +281,20 @@ def _explored(tlats: np.ndarray, tes: np.ndarray,
     return explored
 
 
+def _tied_pick(tlats: np.ndarray, tes: np.ndarray, counts: np.ndarray,
+               metric: str) -> bool:
+    """Whether the last stage's best beam row ties another valid row.
+
+    The stage's rows come sorted by the device's ordering score, so a tie
+    with row 0 shows in row 1.  Scores are recomputed from the fetched
+    per-row latency and energy in their own precision.
+    """
+    if int(counts[-1]) < 2:
+        return False
+    score = metric_score(tlats[-1, :2], tes[-1, :2], metric)
+    return bool(score[0] == score[1])
+
+
 def _plans_from_picks(sets, picks) -> WindowPlan:
     plans = []
     for cs, ci in zip(sets, picks):
@@ -467,7 +486,13 @@ class DeviceBeamEngine:
       O(1) syncs per window, O(n_windows) per schedule, independent of
       models x candidates.  The final plan is re-scored and validated by the
       float64 numpy oracle (``evaluate_window``), so reported metrics stay
-      exact.
+      exact.  The plan itself is ``BeamEngine``'s except where two float64
+      scores lie closer than float32 rounding, or a score falls across a
+      quantisation boundary: the float32 scores keep the float64 ties of
+      placements built from the same layers, under every metric, so the
+      tie rule decides as the host's does (``core.device_search`` states
+      the contract; ``tests/test_device_search.py`` checks it under EDP
+      and under latency, where most windows end in a tie).
 
     ``use_kernel=None`` auto-selects the Pallas kernels on TPU and the
     jax_ref forms elsewhere; ``interpret=True`` runs the kernels anywhere
@@ -644,6 +669,8 @@ class DeviceBeamEngine:
                 cand = built[int(morder[int(failed[0])])][0]
                 _raise_no_disjoint(cand.model_idx, cand.seg_id.shape[0])
             picks = _backtrack(parents, cands)
+            _PICKS.inc()
+            _TIED_PICKS.inc(int(_tied_pick(tlats, tes, counts, metric)))
             plans = []
             for st in range(len(built)):
                 cand, chips, seg_arr = built[int(morder[st])]

@@ -155,7 +155,7 @@ def eval_candidates(db: CostDB, mcm: MCM, cand: BatchedModelCandidates,
             _SEEN_SIGNATURES.add(sig)
             _RECOMPILES.inc()
             obs.event("jit_compile", cat="evaluator", backend=resolved,
-                      batch=int(args[0].shape[0]), layers=Lw)
+                      batch=int(args[5].shape[0]), layers=Lw)
         # the counted host-transfer point: one device->host sync per batch
         out = platform.device_fetch(
             evaluate(*args, **statics, block_b=EVAL_BLOCK_B,

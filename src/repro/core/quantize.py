@@ -58,6 +58,11 @@ def quantize_scores_jax(scores: Any, sig: int = SCORE_SIG) -> Any:
     ``where`` arithmetic (no boolean indexing under trace); values quantised
     in float64 agree bitwise with the host helper up to libm ``log10``
     behaviour at exact powers of ten.
+
+    A float32 score is rounded to its digits exactly
+    (``_round_digits_f32``), so it lands in the bucket the host helper
+    gives the same value: a float32 ``x / scale`` would round once more
+    and move scores near a bucket boundary into the next one.
     """
     import jax.numpy as jnp
 
@@ -66,4 +71,56 @@ def quantize_scores_jax(scores: Any, sig: int = SCORE_SIG) -> Any:
     ax = jnp.where(nz, jnp.abs(x), 1.0)          # dummy 1.0 keeps log finite
     exp = jnp.floor(jnp.log10(ax))
     scale = 10.0 ** (exp - jnp.asarray(sig, exp.dtype))
-    return jnp.where(nz, jnp.round(x / scale) * scale, x)
+    digits = jnp.round(ax / scale)
+    if x.dtype == jnp.float32:
+        digits = _round_digits_f32(ax, exp, sig, digits)
+    return jnp.where(nz, jnp.sign(x) * digits * scale, x)
+
+
+_MAX_POW5 = 13                  # 5**13 < 2**31: the factor fits a uint32
+
+
+def _round_digits_f32(ax: Any, exp: Any, sig: int, approx: Any) -> Any:
+    """``round(ax * 10**(sig - exp))``, half to even, exact, for float32.
+
+    ``ax = m * 2**e`` with a 24-bit integer ``m``, and ``10**j = 5**j *
+    2**j``, so the digits are ``m * 5**j`` shifted right by ``-(e + j)``
+    bits: a 55-bit product, carried in two uint32 words.  Values outside
+    that range (``j`` not in ``[0, 13]``, a shift not in ``[1, 63]``,
+    subnormals) keep ``approx``.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    u32 = jnp.uint32
+    bits = jax.lax.bitcast_convert_type(ax, u32)
+    biased = (bits >> 23).astype(jnp.int32)
+    m = (bits & u32(0x7FFFFF)) | u32(0x800000)
+    j = (sig - exp).astype(jnp.int32)
+    r = 150 - biased - j                         # right shift, -(e + j)
+    ok = (biased > 0) & (j >= 0) & (j <= _MAX_POW5) & (r >= 1) & (r <= 63)
+    f = jnp.asarray([5 ** k for k in range(_MAX_POW5 + 1)],
+                    u32)[jnp.clip(j, 0, _MAX_POW5)]
+    # m * f = hi * 2**32 + lo, from 16-bit limbs (every partial < 2**32)
+    m0, m1 = m & u32(0xFFFF), m >> 16
+    f0, f1 = f & u32(0xFFFF), f >> 16
+    low = m0 * f0
+    mid = m0 * f1 + m1 * f0
+    lo = low + (mid << 16)
+    hi = m1 * f1 + (mid >> 16) + (lo < low).astype(u32)
+    # shift right by r, keeping what falls off (rem) to round half to even
+    big = r >= 32
+    rs = jnp.clip(r, 1, 31).astype(u32)
+    rb = jnp.clip(r - 32, 0, 31).astype(u32)
+    one = u32(1)
+    q = jnp.where(big, hi >> rb, (lo >> rs) | (hi << (32 - rs)))
+    rem_hi = jnp.where(big, hi & ((one << rb) - one), u32(0))
+    rem_lo = jnp.where(big, lo, lo & ((one << rs) - one))
+    half_hi = jnp.where(big & (r > 32),
+                        one << jnp.clip(r - 33, 0, 31).astype(u32), u32(0))
+    half_lo = jnp.where(big, jnp.where(r == 32, u32(0x80000000), u32(0)),
+                        one << (rs - one))
+    above = (rem_hi > half_hi) | ((rem_hi == half_hi) & (rem_lo > half_lo))
+    tie = (rem_hi == half_hi) & (rem_lo == half_lo)
+    q = q + (above | (tie & ((q & one) == one))).astype(u32)
+    return jnp.where(ok, q.astype(ax.dtype), approx)

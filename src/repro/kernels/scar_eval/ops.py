@@ -19,6 +19,18 @@ Two device forms share those terms:
 * ``use_kernel=True`` (Pallas): the dense per-layer form, where the kernel
   reduces every layer of VMEM-resident, candidate-major blocks.
 
+Both forms sum a segment's compute latency exactly: ``split_exact`` ships
+the latency table as two parts on power-of-two grids, coarse enough that
+float32 sums of either part are exact in any order.  A segment's latency
+is then the same bits whatever its offset in the window and whichever
+form sums it, as the float64 oracle's is.  That matters under the
+latency metric, where many placements share one bottleneck segment: a
+float32 prefix-sum difference rounds differently at each offset, would
+split those ties, and would move the beam's tie-break off the oracle's.
+Energy, a sum over segments, keeps the prefix-sum differences, which
+telescope: the segments' sums add back to the window's, so splits of the
+same layers on one class mostly sum alike.
+
 Shape bucketing keeps the jit cache small across a search run: the segment
 axis ``S`` is shrunk to the per-batch max segment count (padded segments
 carry only zeros and are masked), and the batch axis ``B`` is padded up to
@@ -55,6 +67,7 @@ def evaluate_traceable(lat_tab, e_tab, w_bytes, out_bytes, class_map, chips,
                        interpret: bool = False, use_kernel: bool = True):
     """[B, 2] (latency, energy) from compact packed inputs — traceable form.
 
+    ``lat_tab`` is the ``split_exact`` latency table ``[2, Lw, C]``.
     ``chips``/``seg_id``/``last``/``n_segs`` are integer ids (``last`` is
     the window-relative index of each segment's final layer); reductions and
     ``comm_from_parts`` run on device, fused into the jit.  ``prev_idx`` is
@@ -73,7 +86,7 @@ def evaluate_traceable(lat_tab, e_tab, w_bytes, out_bytes, class_map, chips,
     jitted ``evaluate`` wrapper below.
     """
     B, S = chips.shape
-    Lw, C = lat_tab.shape
+    _, Lw, C = lat_tab.shape
     cpos = jnp.maximum(chips, 0)
     seg_cls = class_map[cpos]                                    # [B, S]
     exists = jnp.arange(S)[None, :] < n_segs[:, None]
@@ -98,7 +111,25 @@ def evaluate_traceable(lat_tab, e_tab, w_bytes, out_bytes, class_map, chips,
             act_in, prev_idx if has_prev else None, wait_pair, wait_dram)
         ip_lat = ip_lat + ip_corr
         op_lat = op_lat + op_corr
-    comm_lat = ip_lat + op_lat
+    # the class is constant within a segment, so a segment's sum of a
+    # table is a difference of its per-class prefix sums at the segment
+    # boundaries — O(B*S) gathers, one class column at a time (a gather
+    # from a small 1-D table compiles to a select on the TPU, a 2-D one
+    # to a slow general gather)
+    zrow = jnp.zeros((1, C), jnp.float32)
+
+    def seg_sums(tab):
+        cum = jnp.concatenate([zrow, jnp.cumsum(tab, axis=0)])
+        out = jnp.zeros((B, S), jnp.float32)
+        for c in range(C):
+            col = cum[:, c]
+            out = jnp.where(seg_cls == c, col[lastc + 1] - col[prevc], out)
+        return out
+
+    # the latency table's fine part rides with the comm latency; both
+    # parts sum exactly, so a segment's latency does not depend on where
+    # it sits (``split_exact``)
+    comm_lat = ip_lat + op_lat + seg_sums(lat_tab[1])
     comm_e = ip_e + op_e
     valid = exists.astype(jnp.float32)
 
@@ -107,23 +138,16 @@ def evaluate_traceable(lat_tab, e_tab, w_bytes, out_bytes, class_map, chips,
         # VMEM-resident, candidate-major blocks
         layer_cls = jnp.take_along_axis(seg_cls, seg_id, axis=1)  # [B, Lw]
         pipe = jnp.full((1, B), 1.0 if pipelined else 0.0, jnp.float32)
-        return scar_eval(lat_tab, e_tab, layer_cls.T, seg_id.T, comm_lat.T,
-                         comm_e.T, valid.T, pipe, block_b=block_b,
-                         interpret=interpret).T
+        return scar_eval(lat_tab[0], e_tab, layer_cls.T, seg_id.T,
+                         comm_lat.T, comm_e.T, valid.T, pipe,
+                         block_b=block_b, interpret=interpret).T
 
-    # jax_ref: the class is constant within a segment, so the segment
-    # compute sum is a difference of the prefix-summed per-class table at
-    # the segment boundaries — O(B*S) gathers, the fast non-MXU form.
-    # Semantics are pinned to scar_eval_ref / the numpy oracle by parity
-    # tests (tests/test_evaluator.py, tests/test_kernels.py).
-    zrow = jnp.zeros((1, C), jnp.float32)
-    cum_lat = jnp.concatenate([zrow, jnp.cumsum(lat_tab, axis=0)])
-    cum_e = jnp.concatenate([zrow, jnp.cumsum(e_tab, axis=0)])
-    seg_comp_lat = cum_lat[lastc + 1, seg_cls] - cum_lat[prevc, seg_cls]
-    seg_comp_e = cum_e[lastc + 1, seg_cls] - cum_e[prevc, seg_cls]
-
-    seg_lat = jnp.where(exists, seg_comp_lat + comm_lat, 0.0)
-    energy = jnp.where(exists, seg_comp_e + comm_e, 0.0).sum(axis=1)
+    # jax_ref: prefix-sum differences, the fast non-MXU form; on the
+    # latency table's coarse part they are the kernel's dense sums, bit
+    # for bit.  Semantics are pinned to scar_eval_ref / the numpy oracle
+    # by parity tests (tests/test_evaluator.py, tests/test_kernels.py).
+    seg_lat = jnp.where(exists, seg_sums(lat_tab[0]) + comm_lat, 0.0)
+    energy = jnp.where(exists, seg_sums(e_tab) + comm_e, 0.0).sum(axis=1)
     lat_sum = seg_lat.sum(axis=1)
     if pipelined:
         lat_max = jnp.max(jnp.where(exists, seg_lat, -jnp.inf), axis=1)
@@ -139,6 +163,25 @@ def evaluate_traceable(lat_tab, e_tab, w_bytes, out_bytes, class_map, chips,
 evaluate = partial(jax.jit, static_argnames=(
     "pkg", "mcm_cols", "n_active", "pipelined", "has_prev", "congestion",
     "noc", "block_b", "interpret", "use_kernel"))(evaluate_traceable)
+
+
+def split_exact(tab: np.ndarray) -> np.ndarray:
+    """A float64 ``[Lw, C]`` cost table as float32 ``[2, Lw, C]`` parts.
+
+    Part 0 is each value rounded to a power-of-two grid per column, coarse
+    enough that the column's total is at most 2**23 grid steps, so every
+    float32 sum of its entries is exact, in any order.  Part 1 is the
+    remainder on a grid as fine again, with the same property; what is
+    left out is below 2**-46 of the column's total.
+    """
+    parts, rest = [], np.asarray(tab, np.float64)
+    for _ in range(2):
+        total = np.abs(rest).sum(axis=0)
+        step = np.exp2(np.ceil(np.log2(np.where(total > 0, total, 1.0)
+                                       / 2.0 ** 23)))
+        parts.append(np.round(rest / step) * step)
+        rest = rest - parts[-1]
+    return np.stack(parts).astype(np.float32)
 
 
 def pack_candidates(db, mcm, cand, n_active: int, prev_end=None,
@@ -170,7 +213,7 @@ def pack_candidates(db, mcm, cand, n_active: int, prev_end=None,
     """
     B, Lw = cand.seg_id.shape
     S = max(1, int(cand.n_segs.max()))           # shrink to per-batch max
-    lat_tab = db.lat[cand.start:cand.end].astype(np.float32)
+    lat_tab = split_exact(db.lat[cand.start:cand.end])
     e_tab = db.energy[cand.start:cand.end].astype(np.float32)
     w_bytes = db.w_bytes[cand.start:cand.end].astype(np.float32)
     out_bytes = db.out_bytes[cand.start:cand.end].astype(np.float32)

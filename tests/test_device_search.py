@@ -142,6 +142,59 @@ def test_fused_schedule_matches_host_and_sync_contract(monkeypatch):
     assert split_syncs >= n_batches > dev_syncs
 
 
+@pytest.mark.parametrize("form", ["beam_jax", "jax_ref"])
+@pytest.mark.parametrize("scenario,pattern,size", [
+    ("xr8_outdoors", "het_sides", 3), ("xr8_outdoors", "het_cb", 3),
+    ("xr7_ar_gaming", "het_sides", 6)])
+def test_float32_plans_match_host_under_latency(monkeypatch, scenario,
+                                                pattern, size, form):
+    """Under ``metric="latency"`` a window's plan is often decided by the
+    beam's tie rule: many placements share one bottleneck segment, and so
+    one float64 latency.  The float32 paths — the fused device search and
+    the split jax_ref scoring — keep those ties, and return the host numpy
+    beam's plans, latency and energy, window by window."""
+    monkeypatch.delenv("SCAR_SEARCH_BACKEND", raising=False)
+    sc = get_scenario(scenario)
+    mcm = make_mcm(pattern, rows=size, cols=size, n_pe=256)
+    host = schedule(sc, mcm, SearchConfig(algo="beam", eval_backend="numpy",
+                                          metric="latency"))
+    cfg = (SearchConfig(algo="beam_jax", metric="latency") if form == "beam_jax"
+           else SearchConfig(algo="beam", eval_backend="jax_ref",
+                             metric="latency"))
+    got = schedule(sc, mcm, cfg)
+    assert [w.plan for w in got.windows] == [w.plan for w in host.windows]
+    for g, h in zip(got.windows, host.windows):
+        assert g.result.latency == h.result.latency
+        assert g.result.energy == h.result.energy
+
+
+@pytest.mark.parametrize("metric,tied", [("latency", 5), ("edp", 2)])
+def test_fused_schedule_counts_tied_picks(monkeypatch, metric, tied):
+    """``engine.window.picks`` rises by one per window the fused search
+    plans, and ``engine.window.tied_picks`` by one per window whose best
+    final beam row ties the next on the ordering score.  On xr8's 3x3
+    het_sides package every latency window is tied on its bottleneck
+    segment; under EDP, where energy separates the placements, two are."""
+    from repro import obs
+    from repro.core.engine import _tied_pick
+
+    monkeypatch.delenv("SCAR_SEARCH_BACKEND", raising=False)
+    picks0 = obs.registry.value("engine.window.picks")
+    tied0 = obs.registry.value("engine.window.tied_picks")
+    out = schedule(get_scenario("xr8_outdoors"),
+                   make_mcm("het_sides", rows=3, cols=3, n_pe=256),
+                   SearchConfig(algo="beam_jax", metric=metric))
+    assert obs.registry.value("engine.window.picks") - picks0 == len(
+        out.windows)
+    assert obs.registry.value("engine.window.tied_picks") - tied0 == tied
+
+    lat = np.array([[0.5, 0.5, 0.7], [0.3, 0.3, 0.4]], np.float32)
+    energy = np.array([[1.0, 1.0, 1.0], [2.0, 1.0, 3.0]], np.float32)
+    assert _tied_pick(lat, energy, np.array([3, 3]), "latency")
+    assert not _tied_pick(lat, energy, np.array([3, 3]), "edp")
+    assert not _tied_pick(lat, energy, np.array([3, 1]), "latency")
+
+
 def test_fused_schedule_respects_env_override(monkeypatch):
     """SCAR_SEARCH_BACKEND=beam_jax reroutes a beam schedule through the
     fused device path (the CI shard mechanism)."""
@@ -293,6 +346,26 @@ def test_quantize_scores_jax_matches_numpy():
     got32 = np.asarray(quantize_scores_jax(jnp.asarray(s32), sig=SCORE_SIG))
     ref32 = quantize_scores(s32.astype(np.float64), sig=SCORE_SIG)
     np.testing.assert_allclose(got32, ref32, rtol=10.0 ** -SCORE_SIG)
+
+
+def test_quantize_scores_jax_rounds_float32_digits_exactly():
+    """A float32 score lands in the bucket the host quantiser gives the
+    same value: its digits are rounded exactly, not after a float32
+    ``x / scale`` that rounds once more near a bucket boundary.  Scores
+    from 1e-8 to 1e5, the range the planner's metrics span."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core.quantize import quantize_scores_jax
+
+    rng = np.random.default_rng(7)
+    x = np.concatenate([10.0 ** rng.uniform(-8, 5, 50_000),
+                        rng.uniform(0.01, 0.05, 50_000)]).astype(np.float32)
+    got = np.asarray(jax.jit(
+        lambda v: quantize_scores_jax(v, sig=SCORE_SIG))(jnp.asarray(x)))
+    x64 = x.astype(np.float64)
+    scale = 10.0 ** (np.floor(np.log10(x64)) - SCORE_SIG)
+    want = np.round(quantize_scores(x64, sig=SCORE_SIG) / scale)
+    assert (np.round(got.astype(np.float64) / scale) == want).all()
 
 
 # --------------------------- bucketing helpers ------------------------------
