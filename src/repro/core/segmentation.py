@@ -9,15 +9,24 @@ cross product of per-model top-k's is handed to SCHED.
 """
 from __future__ import annotations
 
+import functools
 import itertools
+from typing import NamedTuple
 
 import numpy as np
+
+from repro import obs
 
 from .chiplet import MCM
 from .maestro import CostDB
 # quantize_scores lives in repro.core.quantize since the device search path
 # (which needs its traceable twin); re-exported here for backward compat.
 from .quantize import quantize_scores
+
+# one of the two per top_k_segmentations call: its split table was memoised
+# already (hit) or built by the call (miss)
+_TABLE_HITS = obs.counter("seg.table_hits")
+_TABLE_MISSES = obs.counter("seg.table_misses")
 
 
 def enumerate_segmentations(n_layers: int, max_segments: int,
@@ -74,6 +83,112 @@ def score_segmentation(db: CostDB, mcm: MCM, start: int,
     return lat * energy
 
 
+class SplitTable(NamedTuple):
+    """A window's candidate segmentations as frozen integer arrays.
+
+    Pure combinatorics of (window length, nodes, cap): no cost table,
+    anchor or package enters it, so one table serves every window of that
+    shape.  Arrays are read-only.
+    """
+
+    segs: tuple[tuple[int, ...], ...]   # candidates, enumeration order
+    length: int                         # window length (every last end)
+    n_segs: np.ndarray                  # [n] segments per candidate
+    ends: np.ndarray                    # [n, S] relative ends, 0-padded
+    valid: np.ndarray                   # [n, S] real (unpadded) slots
+    flat_starts: np.ndarray             # reduceat starts, n-fold tiled slice
+    slots: np.ndarray                   # flat [n*S] index of each valid slot
+
+
+def _table_of(segs) -> SplitTable:
+    """The ``SplitTable`` of a non-empty candidate list."""
+    n = len(segs)
+    n_segs = np.fromiter(map(len, segs), dtype=np.int64, count=n)
+    S = int(n_segs.max())
+    Lw = int(segs[0][-1])
+    if any(int(se[-1]) != Lw for se in segs):
+        # the tiling runs each candidate's last segment to its tile end,
+        # so unequal totals would silently absorb extra layers
+        raise ValueError("all segmentations must cover the same window "
+                         "length (relative last end)")
+    ends = np.zeros((n, S), dtype=np.int64)
+    for i, se in enumerate(segs):
+        ends[i, :len(se)] = se
+    valid = np.arange(S)[None, :] < n_segs[:, None]
+    starts = np.concatenate([np.zeros((n, 1), dtype=np.int64),
+                             ends[:, :-1]], axis=1)
+    # each candidate's segments exactly tile its copy of the window slice,
+    # so consecutive flat start indices delimit every segment
+    flat_starts = (np.arange(n)[:, None] * Lw + starts)[valid]
+    slots = np.flatnonzero(valid)
+    for a in (n_segs, ends, valid, flat_starts, slots):
+        a.setflags(write=False)
+    return SplitTable(tuple(segs), Lw, n_segs, ends, valid, flat_starts,
+                      slots)
+
+
+@functools.lru_cache(maxsize=256)
+def _build_split_table(n_layers: int, max_segments: int,
+                       cap: int) -> SplitTable:
+    _TABLE_MISSES.inc()
+    return _table_of(enumerate_segmentations(n_layers, max_segments, cap))
+
+
+def _split_table(n_layers: int, max_segments: int, cap: int) -> SplitTable:
+    """The memoised ``SplitTable`` of ``enumerate_segmentations``' space.
+
+    ``max_segments`` is clamped as the enumeration clamps it, so equal
+    spaces share one entry.
+    """
+    n_layers = int(n_layers)
+    return _build_split_table(n_layers, max(1, min(int(max_segments),
+                                                   n_layers)), int(cap))
+
+
+def _score_table(db: CostDB, mcm: MCM, start: int, t: SplitTable,
+                 metric: str) -> np.ndarray:
+    """Score every candidate of ``t`` on the window starting at ``start``.
+
+    One ``np.add.reduceat`` over the candidate-tiled ``[lat | energy |
+    w_bytes]`` slice sums every segment of every candidate: each column is
+    reduced over the same rows in the same order as a separate pass per
+    array would, so the sums are bit-identical to that.
+    """
+    pkg = mcm.pkg
+    n, S = t.ends.shape
+    C = db.lat.shape[1]
+    sl = slice(start, start + t.length)
+    rows = np.concatenate([db.lat[sl], db.energy[sl], db.w_bytes[sl, None]],
+                          axis=1)                               # [Lw, 2C+1]
+    sums = np.add.reduceat(np.tile(rows, (n, 1)), t.flat_starts, axis=0)
+    # padded slots: +inf latency keeps them out of the argmin/max, zero
+    # energy and bytes
+    seg = np.empty((n * S, 2 * C + 1))
+    seg[:, :C] = np.inf
+    seg[:, C:] = 0.0
+    seg[t.slots] = sums
+    seg = seg.reshape(n, S, 2 * C + 1)
+    seg_lat_c, seg_e_c, w = seg[:, :, :C], seg[:, :, C:2 * C], seg[:, :, -1]
+
+    cls = np.argmin(seg_lat_c, axis=2)                             # [n, S]
+    lat_best = np.take_along_axis(seg_lat_c, cls[:, :, None],
+                                  axis=2)[:, :, 0]                 # [n, S]
+    e_best = np.take_along_axis(seg_e_c, cls[:, :, None],
+                                axis=2)[:, :, 0]
+    load = w / pkg.dram_bw + pkg.dram_lat_s
+    seg_lat = np.where(t.valid, lat_best + load, -np.inf)
+    seg_e = np.where(t.valid,
+                     e_best + w * 8.0 * pkg.dram_e_pj_per_bit * 1e-12, 0.0)
+    # max == sum for single-segment candidates, so pipelined max covers both
+    lat = seg_lat.max(axis=1)
+    energy = seg_e.sum(axis=1)
+    if metric == "latency":
+        return lat
+    if metric == "energy":
+        return energy
+    return lat * energy
+
+
 def score_segmentations_batch(db: CostDB, mcm: MCM, start: int,
                               segs: list[tuple[int, ...]],
                               metric: str = "edp") -> np.ndarray:
@@ -87,69 +202,26 @@ def score_segmentations_batch(db: CostDB, mcm: MCM, start: int,
     above as the parity oracle (``tests/test_segmentation.py`` pins
     agreement on all ten paper scenarios).
     """
-    pkg = mcm.pkg
-    n = len(segs)
-    if n == 0:
+    if not segs:
         return np.zeros(0)
-    n_segs = np.array([len(se) for se in segs], dtype=np.int64)
-    S = int(n_segs.max())
-    Lw = int(segs[0][-1])
-    if any(int(se[-1]) != Lw for se in segs):
-        # the tiling below runs each candidate's last segment to its tile
-        # end, so unequal totals would silently absorb extra layers
-        raise ValueError("all segmentations must cover the same window "
-                         "length (relative last end)")
-    ends = np.zeros((n, S), dtype=np.int64)          # relative, 0-padded
-    for i, se in enumerate(segs):
-        ends[i, :len(se)] = se
-    valid = np.arange(S)[None, :] < n_segs[:, None]
-    starts = np.concatenate([np.zeros((n, 1), dtype=np.int64),
-                             ends[:, :-1]], axis=1)
-
-    # Segment sums via one reduceat over the candidate-tiled window slice:
-    # each candidate's segments exactly tile its copy, so consecutive flat
-    # start indices delimit every segment (no prefix-sum cancellation).
-    sl = slice(start, start + Lw)
-    flat_starts = (np.arange(n)[:, None] * Lw + starts)[valid]
-    seg_lat_c = np.zeros((n, S, db.lat.shape[1]))
-    seg_e_c = np.zeros_like(seg_lat_c)
-    w = np.zeros((n, S))
-    seg_lat_c[valid] = np.add.reduceat(
-        np.tile(db.lat[sl], (n, 1)), flat_starts, axis=0)
-    seg_e_c[valid] = np.add.reduceat(
-        np.tile(db.energy[sl], (n, 1)), flat_starts, axis=0)
-    w[valid] = np.add.reduceat(np.tile(db.w_bytes[sl], n), flat_starts)
-
-    # padded rows are all-zero; force them out of the argmin/max with +inf
-    seg_lat_c[~valid] = np.inf
-    cls = np.argmin(seg_lat_c, axis=2)                             # [n, S]
-    lat_best = np.take_along_axis(seg_lat_c, cls[:, :, None],
-                                  axis=2)[:, :, 0]                 # [n, S]
-    e_best = np.take_along_axis(seg_e_c, cls[:, :, None],
-                                axis=2)[:, :, 0]
-    load = w / pkg.dram_bw + pkg.dram_lat_s
-    seg_lat = np.where(valid, lat_best + load, -np.inf)
-    seg_e = np.where(valid, e_best + w * 8.0 * pkg.dram_e_pj_per_bit * 1e-12,
-                     0.0)
-    # max == sum for single-segment candidates, so pipelined max covers both
-    lat = seg_lat.max(axis=1)
-    energy = seg_e.sum(axis=1)
-    if metric == "latency":
-        return lat
-    if metric == "energy":
-        return energy
-    return lat * energy
+    return _score_table(db, mcm, start, _table_of(segs), metric)
 
 
 def top_k_segmentations(db: CostDB, mcm: MCM, start: int, end: int,
                         n_nodes: int, k: int = 4, cap: int = 1024,
                         metric: str = "edp") -> list[tuple[int, ...]]:
-    """Heuristic 1 step 1: per-model top-k segmentations by solo score."""
-    cands = enumerate_segmentations(end - start, n_nodes, cap=cap)
-    scores = quantize_scores(
-        score_segmentations_batch(db, mcm, start, cands, metric))
+    """Heuristic 1 step 1: per-model top-k segmentations by solo score.
+
+    The candidate space comes from the memoised split table; every call
+    scores all of it against this window's cost rows.
+    """
+    built = _TABLE_MISSES.value
+    table = _split_table(end - start, n_nodes, cap)
+    if _TABLE_MISSES.value == built:
+        _TABLE_HITS.inc()
+    scores = quantize_scores(_score_table(db, mcm, start, table, metric))
     order = np.argsort(scores, kind="stable")[:k]
-    return [cands[i] for i in order]
+    return [table.segs[i] for i in order]
 
 
 def co_explore(per_model_topk: dict[int, list[tuple[int, ...]]],
