@@ -1,20 +1,12 @@
-"""Framework-side benchmarks: kernel oracles, batched-evaluator throughput,
-and the roofline table from the dry-run artifacts."""
+"""System benchmarks: batched-evaluator throughput, the scoring backends,
+the search engines, candidate construction, the congestion comm model and
+the telemetry overhead."""
 from __future__ import annotations
 
-import json
-import os
-
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from .common import emit, timer
-
-# v5e hardware constants (per chip)
-PEAK_FLOPS = 197e12          # bf16
-HBM_BW = 819e9               # bytes/s
-LINK_BW = 50e9               # bytes/s/link (ICI)
 
 
 def bench_scar_eval_throughput() -> None:
@@ -99,7 +91,6 @@ def bench_eval_backend() -> None:
     parity on live batches while at it.
     """
     import time as _time
-    import jax
     from repro.core.evaluator import eval_candidates
 
     for name, mesh, pattern, path_cap in [("6x6", 6, "het_cross", 128),
@@ -463,88 +454,7 @@ def bench_obs_overhead() -> None:
         f"fused 16x16 schedule time (limit 5%)")
 
 
-def bench_kernel_agreement() -> None:
-    """Kernel-vs-oracle max error at a production-ish tile (interpret mode)."""
-    from repro.kernels.flash_attention import mha
-    from repro.kernels.ssd_scan import gla
-    ks = jax.random.split(jax.random.PRNGKey(0), 4)
-    q = jax.random.normal(ks[0], (1, 256, 4, 128), jnp.bfloat16)
-    k = jax.random.normal(ks[1], (1, 256, 2, 128), jnp.bfloat16)
-    v = jax.random.normal(ks[2], (1, 256, 2, 128), jnp.bfloat16)
-    with timer() as t:
-        out = mha(q, k, v, causal=True, interpret=True)
-        ref = mha(q, k, v, causal=True, use_kernel=False)
-    err = float(jnp.max(jnp.abs(out.astype(jnp.float32)
-                                - ref.astype(jnp.float32))))
-    emit("flash_attention_agreement", t.us, f"max_abs_err={err:.2e}")
-    qg = jax.random.normal(ks[0], (1, 512, 2, 64))
-    kg = jax.random.normal(ks[1], (1, 512, 2, 64))
-    vg = jax.random.normal(ks[2], (1, 512, 2, 64))
-    a = -jax.nn.softplus(jax.random.normal(ks[3], (1, 512, 2)))
-    with timer() as t:
-        out = gla(qg, kg, vg, a, chunk=128, interpret=True)
-        ref = gla(qg, kg, vg, a, chunk=128, use_kernel=False)
-    err = float(jnp.max(jnp.abs(out - ref)))
-    emit("ssd_scan_agreement", t.us, f"max_abs_err={err:.2e}")
-
-
-def roofline_terms(rec: dict) -> dict:
-    """Three roofline terms (seconds) from a dry-run record (per device)."""
-    ct = rec["cost"]["flops"] / PEAK_FLOPS
-    mt = rec["cost"]["bytes_accessed"] / HBM_BW
-    lt = rec["collectives"]["total_link_bytes"] / LINK_BW
-    dom = max(("compute", ct), ("memory", mt), ("collective", lt),
-              key=lambda kv: kv[1])
-    return {"compute_s": ct, "memory_s": mt, "collective_s": lt,
-            "bottleneck": dom[0],
-            "roofline_s": max(ct, mt, lt)}
-
-
-def model_flops(arch: str, shape: str) -> float:
-    """MODEL_FLOPS: 6*N*D train / 2*N*D prefill / 2*N per-token decode,
-    N = active non-embedding params."""
-    from repro.launch.cells import SHAPES
-    from repro.models import get_arch
-    cfg = get_arch(arch)
-    n_total = cfg.param_count()
-    embed = cfg.vocab * cfg.d_model * (1 if cfg.tie_embeddings else 2)
-    n = n_total - embed
-    if cfg.moe is not None:
-        m = cfg.moe
-        expert_total = (cfg.n_super_blocks * m.n_experts * 3 * cfg.d_model
-                        * m.expert_d_ff)
-        active_frac = m.top_k / m.n_experts
-        n = n - expert_total + expert_total * active_frac
-    s = SHAPES[shape]
-    tokens = s["batch"] * (s["seq"] if s["kind"] != "decode" else 1)
-    mult = 6 if s["kind"] == "train" else 2
-    return mult * n * tokens
-
-
-def bench_roofline_table(path: str = "dryrun_results.jsonl") -> None:
-    """The EXPERIMENTS.md roofline table (also emitted as bench rows)."""
-    if not os.path.exists(path):
-        emit("roofline_table", 0.0, "missing_dryrun_results")
-        return
-    recs = [json.loads(line) for line in open(path)]
-    for r in recs:
-        if "error" in r or not r["mesh"].startswith("single"):
-            continue
-        n_dev = 256
-        terms = roofline_terms(r)
-        mf = model_flops(r["arch"], r["shape"]) / n_dev
-        useful = mf / max(r["cost"]["flops"], 1.0)
-        frac = terms["compute_s"] / terms["roofline_s"]
-        emit(f"roofline_{r['arch']}_{r['shape']}", r["compile_s"] * 1e6,
-             f"compute_s={terms['compute_s']:.3e};"
-             f"memory_s={terms['memory_s']:.3e};"
-             f"collective_s={terms['collective_s']:.3e};"
-             f"bottleneck={terms['bottleneck']};"
-             f"model_flops_ratio={useful:.3f};"
-             f"compute_fraction={frac:.3f}")
-
-
 ALL = [bench_scar_eval_throughput, bench_eval_backend,
        bench_sched_throughput, bench_fused_search,
        bench_candidate_construction, bench_comm_congestion,
-       bench_obs_overhead, bench_kernel_agreement, bench_roofline_table]
+       bench_obs_overhead]

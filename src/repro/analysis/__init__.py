@@ -1,3 +1,1 @@
-from . import hlo_cost
 from . import lint
-from . import roofline

@@ -1,4 +1,1 @@
-# NOTE: do not import dryrun here (it sets XLA_FLAGS at import time).
-from .mesh import make_production_mesh, make_test_mesh
-from .platform import (device_fetch, jax_enable_x64, reset_sync_count,
-                       set_host_device_count, set_platform, sync_count)
+from .platform import device_fetch, reset_sync_count, sync_count

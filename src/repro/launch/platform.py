@@ -2,17 +2,10 @@
 
 The device search path (``core.engine.DeviceBeamEngine``) and the evaluator
 backends run the same code on CPU, interpret-mode Pallas, and real
-accelerators; this module is the one place that configures which, following
-the bayespec config idiom:
+accelerators; this module holds the little runtime configuration they share:
 
-* ``set_platform("cpu"|"gpu"|"tpu")`` — pin the jax platform (call before
-  any array op; jax latches the backend on first use);
-* ``jax_enable_x64(True)`` — process-global float64 (the device search path
-  does NOT need this: it scopes x64 per-program via ``enable_x64()``, the
-  one scoped-x64 helper, built on ``jax.enable_x64``);
-* ``set_host_device_count(n)`` — split the host CPU into ``n`` XLA devices
-  (``--xla_force_host_platform_device_count``) for multi-device tests.
-  Must run before jax initialises its backends;
+* ``enable_x64()`` — scoped float64 (the device search path scopes x64
+  per-program with it, built on ``jax.enable_x64``);
 * ``use_compile_cache(root)`` — place JAX's persistent compilation cache.  Entry
   points (scripts, examples, the benchmark harness) call it; library code
   and tests never do.
@@ -33,41 +26,15 @@ telemetry exporters read the *same* integer, so they can never disagree.
 from __future__ import annotations
 
 import os
-import re
 
 from repro.obs import registry as _obs_registry
 
-__all__ = ["set_platform", "jax_enable_x64", "enable_x64",
-           "set_host_device_count", "use_compile_cache", "device_fetch",
-           "sync_count", "reset_sync_count"]
+__all__ = ["enable_x64", "use_compile_cache", "device_fetch", "sync_count",
+           "reset_sync_count"]
 
 # The one sync counter; module-level handle so device_fetch pays a single
 # attribute increment per call.
 _SYNC = _obs_registry.counter("launch.platform.sync_count")
-
-
-def set_platform(platform: str = "cpu") -> None:
-    """Pin the jax platform to ``'cpu'``, ``'gpu'`` or ``'tpu'``.
-
-    Only takes effect before jax initialises its backends (i.e. call it at
-    program start, before the first array op).
-    """
-    import jax
-
-    jax.config.update("jax_platform_name", platform)
-
-
-def jax_enable_x64(use_x64: bool = True) -> None:
-    """Process-global 64-bit mode (``jax.config jax_enable_x64``).
-
-    Prefer the scoped ``enable_x64()`` context manager where possible — the
-    device search engine uses the scoped form so the float32 evaluator
-    paths are unaffected; this global switch exists for scripts that want
-    x64 everywhere (bayespec idiom).
-    """
-    import jax
-
-    jax.config.update("jax_enable_x64", use_x64)
 
 
 def enable_x64():
@@ -100,20 +67,6 @@ def use_compile_cache(root: str) -> str:
     path = os.path.join(os.path.abspath(root), ".jax_cache")
     jax.config.update("jax_compilation_cache_dir", path)
     return path
-
-
-def set_host_device_count(n: int) -> None:
-    """Expose the host CPU as ``n`` XLA devices (for multi-device tests).
-
-    Rewrites ``XLA_FLAGS`` (idempotent: an existing
-    ``--xla_force_host_platform_device_count`` flag is replaced).  Must run
-    before jax initialises its backends, typically at the top of a script.
-    """
-    xla_flags = os.environ.get("XLA_FLAGS", "")
-    xla_flags = re.sub(r"--xla_force_host_platform_device_count=\S+", "",
-                      xla_flags).split()
-    os.environ["XLA_FLAGS"] = " ".join(
-        [f"--xla_force_host_platform_device_count={n}"] + xla_flags)
 
 
 def device_fetch(tree):

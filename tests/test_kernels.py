@@ -1,95 +1,33 @@
-"""Per-kernel tests: shape/dtype sweeps, interpret-mode kernel vs ref.py
-oracle (deliverable c).  The randomised scar_eval-vs-core-evaluator property
-lives in ``test_cost_properties.py`` (hypothesis-gated)."""
-import jax
+"""Per-kernel tests: the Pallas kernels in interpret mode against their
+jax_ref forms and the float64 core evaluator.  The randomised
+scar_eval-vs-core-evaluator property lives in ``test_cost_properties.py``
+(hypothesis-gated)."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.flash_attention import mha
-from repro.kernels.ssd_scan import gla
+from repro.core import SCENARIO_NAMES
 
 
-def _tol(dt):
-    return dict(rtol=2e-2, atol=2e-2) if dt == jnp.bfloat16 \
-        else dict(rtol=2e-5, atol=2e-5)
-
-
-@pytest.mark.parametrize("B,S,Hq,Hkv,D", [
-    (1, 128, 1, 1, 64), (2, 256, 4, 2, 64), (1, 256, 8, 1, 128),
-    (2, 384, 6, 2, 64), (1, 512, 2, 2, 128),
-])
-@pytest.mark.parametrize("causal", [True, False])
-@pytest.mark.parametrize("dt", [jnp.float32, jnp.bfloat16])
-def test_flash_attention_matches_ref(B, S, Hq, Hkv, D, causal, dt):
-    ks = jax.random.split(jax.random.PRNGKey(B * S + D), 3)
-    q = jax.random.normal(ks[0], (B, S, Hq, D), dt)
-    k = jax.random.normal(ks[1], (B, S, Hkv, D), dt)
-    v = jax.random.normal(ks[2], (B, S, Hkv, D), dt)
-    out = mha(q, k, v, causal=causal, interpret=True)
-    ref = mha(q, k, v, causal=causal, use_kernel=False)
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(ref, np.float32), **_tol(dt))
-
-
-def test_flash_attention_block_shapes_sweep():
-    ks = jax.random.split(jax.random.PRNGKey(7), 3)
-    q = jax.random.normal(ks[0], (1, 256, 2, 64), jnp.float32)
-    k = jax.random.normal(ks[1], (1, 256, 2, 64), jnp.float32)
-    v = jax.random.normal(ks[2], (1, 256, 2, 64), jnp.float32)
-    ref = mha(q, k, v, causal=True, use_kernel=False)
-    for bq, bk in [(64, 64), (128, 64), (64, 128), (256, 256), (128, 256)]:
-        out = mha(q, k, v, causal=True, block_q=bq, block_k=bk,
-                  interpret=True)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=2e-5, atol=2e-5)
-
-
-@pytest.mark.parametrize("B,L,H,N,P,chunk", [
-    (1, 128, 1, 16, 16, 64), (2, 256, 2, 64, 64, 128),
-    (1, 512, 4, 32, 64, 128), (1, 256, 2, 64, 64, 256),
-])
-@pytest.mark.parametrize("dt", [jnp.float32, jnp.bfloat16])
-def test_ssd_scan_matches_ref(B, L, H, N, P, chunk, dt):
-    ks = jax.random.split(jax.random.PRNGKey(L + N), 4)
-    q = jax.random.normal(ks[0], (B, L, H, N), dt)
-    k = jax.random.normal(ks[1], (B, L, H, N), dt)
-    v = jax.random.normal(ks[2], (B, L, H, P), dt)
-    a = -jax.nn.softplus(jax.random.normal(ks[3], (B, L, H)))
-    out = gla(q, k, v, a, chunk=chunk, interpret=True)
-    ref = gla(q, k, v, a, chunk=chunk, use_kernel=False)
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(ref, np.float32), **_tol(dt))
-
-
-def test_ssd_scan_state_carry_across_chunks():
-    """Decay ~ 1 (a ~ 0): output at position t is the running sum of kv —
-    checks the scratch state survives chunk boundaries."""
-    B, L, H, N, P = 1, 256, 1, 8, 8
-    q = jnp.ones((B, L, H, N)) / N
-    k = jnp.ones((B, L, H, N))
-    v = jnp.ones((B, L, H, P))
-    a = jnp.zeros((B, L, H))
-    out = gla(q, k, v, a, chunk=64, interpret=True)
-    expect = jnp.arange(1, L + 1, dtype=jnp.float32)
-    np.testing.assert_allclose(np.asarray(out[0, :, 0, 0]),
-                               np.asarray(expect), rtol=1e-5)
-
-
+@pytest.mark.parametrize("scenario", SCENARIO_NAMES)
+@pytest.mark.parametrize("pattern", ["het_cb", "het_sides", "het_cross"])
 @pytest.mark.parametrize("pipelined", [True, False])
 @pytest.mark.parametrize("prev_end", [None, 3])
-def test_scar_eval_kernel_matches_core_evaluator_seeded(pipelined, prev_end):
+def test_scar_eval_kernel_matches_core_evaluator_seeded(scenario, pattern,
+                                                        pipelined, prev_end):
     """Kernel == jax_ref form == numpy core evaluator on a seeded random
-    plan batch, in both latency modes (the bridge used to hard-code
-    ``pipelined=True``) and with/without a locality anchor."""
+    plan batch, for every scenario on every chiplet-class map (4096-PE
+    datacenter and 256-PE AR/VR cost tables), in both latency modes (the
+    bridge used to hard-code ``pipelined=True``) and with/without a
+    locality anchor."""
     from candidate_utils import random_candidate_batch
     from repro.core import get_scenario, make_mcm
     from repro.core.cost import eval_model_candidates
     from repro.core.maestro import build_cost_db
     from repro.kernels.scar_eval import evaluate, pack_candidates
 
-    sc = get_scenario("xr10_vr_gaming")
-    mcm = make_mcm("het_sides", n_pe=256)
+    sc = get_scenario(scenario)
+    mcm = make_mcm(pattern, n_pe=4096 if scenario.startswith("dc") else 256)
     db = build_cost_db(sc, mcm.classes, mcm.pkg)
     cand = random_candidate_batch(np.random.default_rng(7), db, mcm)
     lat_ref, e_ref = eval_model_candidates(db, mcm, cand, n_active=2,
